@@ -31,6 +31,8 @@ import pathlib
 import shutil
 import time
 
+from repro import xla_cache
+
 from repro.experiments import (ExperimentSpec, best_improvements,
                                load_artifact_results, render_sweep_table,
                                run_experiment, write_artifact)
@@ -72,7 +74,8 @@ def main(argv=None) -> int:
                          "(skip, rather than recompute, missing ones)")
     ap.add_argument("--cold-xla-cache", action="store_true",
                     help="clear artifacts/xla_cache before the sweep so "
-                         "compile_s measures a genuinely cold run")
+                         "compile_s measures a genuinely cold run (refused "
+                         "when JAX_COMPILATION_CACHE_DIR places the cache)")
     ap.add_argument("--timing-tag", default="",
                     help="suffix for the wall-clock record "
                          "(sweep-timing-{engine}[-TAG].json) so a warm "
@@ -80,6 +83,14 @@ def main(argv=None) -> int:
     args = ap.parse_args(argv)
     if args.full:
         args.scale, args.seeds = 1.0, 10
+    if args.cold_xla_cache:
+        if xla_cache.placed_from_outside():
+            ap.error(f"--cold-xla-cache never deletes the "
+                     f"{xla_cache.ENV_VAR} directory; unset the variable "
+                     "or clear the directory yourself")
+        shutil.rmtree(xla_cache.cache_dir(), ignore_errors=True)
+    if args.engine == "jax":
+        xla_cache.enable_compilation_cache()
 
     configure_observability(args)
     scenario = scenario_from_args(args)
@@ -133,9 +144,7 @@ def main(argv=None) -> int:
 
         # classify the run for the perf gate *before* the sweep touches
         # the cache: cold = no persisted XLA compilations available
-        xla_dir = ARTIFACTS / "xla_cache"
-        if args.cold_xla_cache and xla_dir.exists():
-            shutil.rmtree(xla_dir)
+        xla_dir = xla_cache.cache_dir()
         xla_cache_state = ("warm" if xla_dir.exists()
                           and any(xla_dir.iterdir()) else "cold")
 
@@ -151,7 +160,6 @@ def main(argv=None) -> int:
                 # but keep XLA compilations persistent (results-neutral)
                 cache_dir=None if args.no_reuse
                 else str(ARTIFACTS / "sweep_cache"),
-                xla_cache_dir=str(ARTIFACTS / "xla_cache"),
                 backend_options=backend_options_from_args(args))
             batch_wall = time.monotonic() - t_sw
             all_results.update(computed)

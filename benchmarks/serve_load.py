@@ -147,6 +147,9 @@ def main(argv=None) -> int:
                     help="timing record path (default: "
                          "artifacts/serve-timing-{engine}.json)")
     args = ap.parse_args(argv)
+    if args.engine == "jax":
+        from repro.xla_cache import enable_compilation_cache
+        enable_compilation_cache()
 
     if args.cache_dir:
         cache_dir = args.cache_dir

@@ -136,6 +136,9 @@ def main(argv=None):
                          "crosschecked unless --crosscheck overrides")
     ap.add_argument("--out", default="")
     args = ap.parse_args(argv)
+    if args.engine == "jax" or args.compare_engines:
+        from repro.xla_cache import enable_compilation_cache
+        enable_compilation_cache()
 
     if args.compare_engines:
         report = compare_engines(spec_from_args(args),

@@ -828,12 +828,12 @@ def schedule_tick(p: PassParams, state, alloc, remaining, start_t, act,
             room = jnp.where(expandable,
                              jnp.maximum(p.max_nodes - alloc, 0), 0)
             pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
-            if expand_backend == "bisect":
-                give = give_asc_prefix(pr, room, idle, prio_lo - 1, prio_hi)
-            else:
+            if expand_backend in ("pallas", "pallas-interpret"):
                 give = _pallas_give(pr, room, idle,
                                     interpret=expand_backend
                                     == "pallas-interpret")
+            else:  # bisect, and the fused backends' unfused statics
+                give = give_asc_prefix(pr, room, idle, prio_lo - 1, prio_hi)
             return alloc + give
 
     return (state,

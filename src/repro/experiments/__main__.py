@@ -88,6 +88,9 @@ def main(argv=None, prog=None, epilog=None) -> int:
 
     configure_observability(args)
     spec = spec_from_args(args)
+    if spec.engine == "jax":
+        from repro.xla_cache import enable_compilation_cache
+        enable_compilation_cache()
     if args.compare_scenarios:
         rc = compare_scenarios(spec, args)
         flush_observability(args)

@@ -22,6 +22,7 @@ This module never imports jax.
 from __future__ import annotations
 
 import concurrent.futures
+import multiprocessing
 import os
 import time
 from typing import Dict, List, Optional, Tuple
@@ -113,8 +114,12 @@ def run_cells(spec: ExperimentSpec,
 
     if workers > 1 and len(todo) > 1:
         tasks = [(spec, name, cell) for name, cell in todo]
+        # spawn, never fork: a forked child inherits the runtime of a
+        # parent that already holds the accelerator; a spawned worker
+        # starts clean and, like this module, never imports jax
         with concurrent.futures.ProcessPoolExecutor(
-                max_workers=min(workers, len(tasks))) as pool:
+                max_workers=min(workers, len(tasks)),
+                mp_context=multiprocessing.get_context("spawn")) as pool:
             futures = [pool.submit(_worker, t) for t in tasks]
             for fut in concurrent.futures.as_completed(futures):
                 key, m, wall_s = fut.result()

@@ -40,7 +40,6 @@ event compression), ``aot_warmup`` (background ladder pre-compilation),
 """
 from __future__ import annotations
 
-import pathlib
 import time
 from typing import Dict, List, Optional, Tuple
 
@@ -56,17 +55,6 @@ from repro.sweep.shard import (ShardConfig, describe_plan,
                                simulate_lanes_chunked)
 
 from .spec import Cell, ExperimentSpec, prepare_workload
-
-
-def enable_compilation_cache(path) -> None:
-    """Persist XLA compilations so repeated sweeps skip compile time."""
-    import jax
-    try:
-        pathlib.Path(path).mkdir(parents=True, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", str(path))
-        jax.config.update("jax_persistent_cache_min_compile_time_secs", 1.0)
-    except Exception:  # older jax without the persistent cache knobs
-        pass
 
 
 def run_cells(spec: ExperimentSpec,
@@ -113,7 +101,8 @@ def run_cells(spec: ExperimentSpec,
                                "peak_lane_width": 0,
                                "compile_s": 0.0, "execute_s": 0.0,
                                "compile_variants": 0,
-                               "retraces": 0, "escalations": 0,
+                               "retraces": 0, "aot_rejits": 0,
+                               "escalations": 0,
                                "warm_hits": 0, "compressed_events": 0,
                                "sched_steps": 0}
     for structure, group in groups.items():
@@ -203,6 +192,7 @@ def run_cells(spec: ExperimentSpec,
                 "execute_s": float(res["execute_s"]),
                 "compile_variants": int(res.get("compile_variants", 0)),
                 "retraces": int(res["retraces"]),
+                "aot_rejits": int(res["aot_rejits"]),
                 "escalations": int(res["escalations"]),
                 "warm_hits": int(res["warm_hits"]),
                 "sched_steps": int(np.sum(res["sched_steps"])),
@@ -213,6 +203,7 @@ def run_cells(spec: ExperimentSpec,
             variants_peak = max(variants_peak,
                                 int(res.get("compile_variants", 0)))
             info["retraces"] += int(res["retraces"])
+            info["aot_rejits"] += int(res["aot_rejits"])
             info["escalations"] += int(res["escalations"])
             info["warm_hits"] += int(res["warm_hits"])
             info["sched_steps"] += int(np.sum(res["sched_steps"]))

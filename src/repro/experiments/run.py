@@ -46,7 +46,6 @@ def _backend(engine: str):
 
 def run_experiment(spec: ExperimentSpec, *,
                    cache_dir: Optional[str] = None,
-                   xla_cache_dir: Optional[str] = None,
                    backend_options: Optional[Dict] = None,
                    crosscheck: int = 0,
                    crosscheck_seed: int = 0,
@@ -54,12 +53,9 @@ def run_experiment(spec: ExperimentSpec, *,
     """Run ``spec``; returns ``{workload: results}`` in the artifact schema.
 
     ``cache_dir`` enables the shared per-cell store (both engines read and
-    write it); on the jax engine it also turns on the persistent XLA
-    compilation cache next to it (``<cache_dir>/../xla_cache``), or at
-    ``xla_cache_dir`` when given — pass the latter to keep compilations
-    persistent while bypassing the result store (e.g. timing runs that
-    must recompute every cell).  ``backend_options`` are results-neutral
-    tuning knobs
+    write it).  The persistent XLA compilation cache is the entry point's
+    business (:func:`repro.xla_cache.enable_compilation_cache`), not the
+    store's.  ``backend_options`` are results-neutral tuning knobs
     (des: ``workers``; jax: ``window``, ``chunk``, ``expand_backend``).
     ``crosscheck N`` re-runs N seeded-sampled cells per workload through
     the reference DES (jax engine only; the DES *is* the reference —
@@ -95,12 +91,6 @@ def run_experiment(spec: ExperimentSpec, *,
                          for n, (s, p, sd) in todo],
     }
     if todo:
-        xla_dir = xla_cache_dir or (
-            pathlib.Path(cache_dir).parent / "xla_cache" if cache_dir
-            else None)
-        if spec.engine == "jax" and xla_dir:
-            from .backend_jax import enable_compilation_cache
-            enable_compilation_cache(xla_dir)
         computed, info = _backend(spec.engine).run_cells(
             spec, todo, store, fingerprints, options=backend_options,
             verbose=verbose)

@@ -22,6 +22,9 @@ nothing, ``need == 0`` takes nothing, ``idle == 0`` gives nothing), so
 running every phase unconditionally yields bitwise-identical outputs —
 asserted by the interpret-mode parity tests in ``tests/test_passes.py``
 and the engine-level crosscheck (``--expand-backend fused-interpret``).
+That Mosaic accepts the kernel for a TPU v5e is asserted separately, by
+compiling it for a described chip in ``tests/test_tpu_compile.py``; the
+in-kernel prefix sums are :func:`repro.kernels.waterfill.lane_cumsum`.
 
 Balanced (AVG) structure and workload-class queue priority are not fused;
 :func:`repro.core.passes.schedule_tick` falls back to the reference pass
@@ -38,14 +41,15 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.jobs import DONE, QUEUED, RUNNING
+from repro.kernels.waterfill import lane_cumsum
 
 _SHADOW_EPS = 1e-3  # must match repro.core.passes._SHADOW_EPS
 
 
 def _first_true(mask):
-    """``passes.first_true`` without argmax (TPU iota-free): the slot where
-    the inclusive cumsum first hits 1."""
-    return mask & (jnp.cumsum(mask.astype(jnp.int32), axis=-1) == 1)
+    """``passes.first_true`` without argmax: the slot where the inclusive
+    prefix count first hits 1."""
+    return mask & (lane_cumsum(mask.astype(jnp.int32)) == 1)
 
 
 def _speedup_f32(a, p):
@@ -69,7 +73,7 @@ def _take_desc_prefix(prio, amount, need, lo0: int, hi0: int):
     theta = hi
     rem = need - s_hi
     tie = prio == theta
-    before = jnp.cumsum(jnp.where(tie, amount, 0), axis=-1)
+    before = lane_cumsum(jnp.where(tie, amount, 0))
     tie_take = jnp.clip(rem - (before - amount), 0, amount)
     return jnp.where(prio > theta, amount, jnp.where(tie, tie_take, 0))
 
@@ -119,9 +123,9 @@ def _tick_kernel(state_ref, alloc_ref, remaining_ref, start_ref, act_ref,
     sfloor, pref = sfloor_ref[...], pref_ref[...]
     mx = mx_ref[...]
     pfrac, wall = pfrac_ref[...], wall_ref[...]  # (1, W) f32
-    capacity = cap_ref[0, 0]                     # scalars
-    t_now = jnp.full((1, 1), tnow_ref[0, 0], jnp.float32)
-    depth = depth_ref[0, 0]
+    capacity = cap_ref[...]                      # (1, 1) lane scalars
+    t_now = tnow_ref[...]
+    depth = depth_ref[...]
 
     running = state == RUNNING
     free = capacity - jnp.sum(jnp.where(running, alloc, 0), axis=-1,
@@ -129,7 +133,7 @@ def _tick_kernel(state_ref, alloc_ref, remaining_ref, start_ref, act_ref,
 
     # -- Step 1: FCFS prefix + head fallback ------------------------------
     queued = (state == QUEUED) & act
-    cumw = jnp.cumsum(jnp.where(queued, want, 0), axis=-1)
+    cumw = lane_cumsum(jnp.where(queued, want, 0))
     s1 = queued & (cumw <= free)
     used = jnp.max(jnp.where(s1, cumw, 0), axis=-1, keepdims=True)
     leftover = free - used
@@ -154,11 +158,10 @@ def _tick_kernel(state_ref, alloc_ref, remaining_ref, start_ref, act_ref,
     hwant = jnp.sum(jnp.where(h_mask, want, 0), axis=-1, keepdims=True)
     has_head = hfloor > 0
 
+    behind_head = act & ~h_mask
     if depth_bounded:
-        ranks = jnp.cumsum(queued.astype(jnp.int32), axis=-1)
-        depth_ok = ranks <= depth + 1
-    else:
-        depth_ok = jnp.full(state.shape, True)
+        ranks = lane_cumsum(queued.astype(jnp.int32))
+        behind_head = behind_head & (ranks <= depth + 1)
     run = state == RUNNING
     est = jnp.where(run,
                     t_now + remaining * wall / _speedup_f32(alloc, pfrac),
@@ -172,19 +175,19 @@ def _tick_kernel(state_ref, alloc_ref, remaining_ref, start_ref, act_ref,
 
     tfit = t_now + wall / _speedup_f32(want, pfrac) <= shadow + _SHADOW_EPS
     for _ in range(fill_rounds):
-        cand = (state == QUEUED) & act & ~h_mask & depth_ok
+        cand = (state == QUEUED) & behind_head
         c = cand & tfit & (want <= free)
-        cum = jnp.cumsum(jnp.where(c, want, 0), axis=-1)
+        cum = lane_cumsum(jnp.where(c, want, 0))
         s = c & (cum <= free)
         free = free - jnp.max(jnp.where(s, cum, 0), axis=-1, keepdims=True)
         lim = jnp.minimum(free, extra)
         c2 = cand & ~s & ~tfit & (want <= lim)
-        cum2 = jnp.cumsum(jnp.where(c2, want, 0), axis=-1)
+        cum2 = lane_cumsum(jnp.where(c2, want, 0))
         s2 = c2 & (cum2 <= lim)
         take2 = jnp.max(jnp.where(s2, cum2, 0), axis=-1, keepdims=True)
         lim3 = jnp.minimum(free - take2, extra - take2)
         c3 = cand & ~s & ~s2 & ~tfit & (floor <= lim3)
-        cum3 = jnp.cumsum(jnp.where(c3, floor, 0), axis=-1)
+        cum3 = lane_cumsum(jnp.where(c3, floor, 0))
         s3 = c3 & (cum3 <= lim3)
         take3 = jnp.max(jnp.where(s3, cum3, 0), axis=-1, keepdims=True)
 
@@ -247,11 +250,11 @@ def fused_schedule_tick(p, state, alloc, remaining, start_t, act,
 
     def row_i32(a, fill=0):
         a = jnp.broadcast_to(jnp.asarray(a), lane_shape + (W0,))
-        return a.reshape(B, W0).astype(jnp.int32), jnp.int32(fill)
+        return a.reshape(B, 1, W0).astype(jnp.int32), jnp.int32(fill)
 
     def row_f32(a, fill=0.0):
         a = jnp.broadcast_to(jnp.asarray(a), lane_shape + (W0,))
-        return a.reshape(B, W0).astype(jnp.float32), jnp.float32(fill)
+        return a.reshape(B, 1, W0).astype(jnp.float32), jnp.float32(fill)
 
     rows = [row_i32(state, DONE),
             row_i32(alloc), row_f32(remaining), row_f32(start_t),
@@ -265,22 +268,24 @@ def fused_schedule_tick(p, state, alloc, remaining, start_t, act,
     W = max(128, -(-W0 // 128) * 128)
     pad = W - W0
     if pad:
-        rows = [(jnp.pad(a, ((0, 0), (0, pad)), constant_values=f), f)
+        rows = [(jnp.pad(a, ((0, 0), (0, 0), (0, pad)), constant_values=f),
+                 f)
                 for a, f in rows]
     arrs = [a for a, _ in rows]
 
     def scal(v, dtype):
         v = jnp.broadcast_to(jnp.asarray(v), lane_shape)
-        return v.reshape(B, 1).astype(dtype)
+        return v.reshape(B, 1, 1).astype(dtype)
 
     arrs.append(scal(capacity, jnp.int32))
     arrs.append(scal(t_now, jnp.float32))
     depth_bounded = backfill_depth is not None
     arrs.append(scal(backfill_depth if depth_bounded else 0, jnp.int32))
 
-    row_spec = pl.BlockSpec((1, W), lambda b: (b, 0))
-    scal_spec = pl.BlockSpec((1, 1), lambda b: (b, 0),
-                             memory_space=pltpu.SMEM)
+    # one lane per grid step: (B, 1, W) rows with a squeezed lane dim, so
+    # the block's last two dims equal the array's (Mosaic's tiling rule)
+    row_spec = pl.BlockSpec((None, 1, W), lambda b: (b, 0, 0))
+    scal_spec = pl.BlockSpec((None, 1, 1), lambda b: (b, 0, 0))
     out = pl.pallas_call(
         functools.partial(_tick_kernel, fill_rounds=fill_rounds,
                           prio_lo=prio_lo, prio_hi=prio_hi,
@@ -289,12 +294,12 @@ def fused_schedule_tick(p, state, alloc, remaining, start_t, act,
         grid=(B,),
         in_specs=[row_spec] * 13 + [scal_spec] * 3,
         out_specs=[row_spec] * 3,
-        out_shape=[jax.ShapeDtypeStruct((B, W), jnp.int32),
-                   jax.ShapeDtypeStruct((B, W), jnp.int32),
-                   jax.ShapeDtypeStruct((B, W), jnp.float32)],
+        out_shape=[jax.ShapeDtypeStruct((B, 1, W), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, W), jnp.int32),
+                   jax.ShapeDtypeStruct((B, 1, W), jnp.float32)],
         interpret=interpret,
     )(*arrs)
-    state2, alloc2, start2 = (a[:, :W0] for a in out)
+    state2, alloc2, start2 = (a[:, 0, :W0] for a in out)
     return (state2.reshape(lane_shape + (W0,)),
             alloc2.reshape(lane_shape + (W0,)),
             start2.reshape(lane_shape + (W0,)))

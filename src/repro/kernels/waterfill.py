@@ -7,9 +7,10 @@ target is met.  At Eagle scale (143k jobs x one scheduler invocation per
 event) this is the simulator's dominant vector op.
 
 Kernel structure: 1-D sequential grid over job blocks; the running
-prefix total is a single SMEM scalar carried across grid steps.  Each block
-does an in-VMEM cumulative sum, clips against the remaining target, and
-writes its take — one HBM read and one HBM write per element, the memory
+prefix total is a (1, 1) VMEM scalar carried across grid steps.  Each
+block does an in-VMEM log-step prefix sum (:func:`lane_cumsum`), clips
+against the remaining target, and writes its take — one HBM read and
+one HBM write per element, the memory
 roofline for this op (XLA's global cumsum materializes the full prefix
 array through HBM twice).
 
@@ -18,29 +19,42 @@ nodes, Table 2).
 """
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _waterfill_kernel(target_ref, cap_ref, take_ref, carry_ref, *,
-                      n_blocks: int):
-    i = pl.program_id(0)
+def lane_cumsum(x):
+    """Inclusive int32 prefix sum along the last (lane) axis, in-kernel.
 
-    @pl.when(i == 0)
+    Mosaic has no ``cumsum`` lowering, so this is a log-step
+    (Hillis-Steele) scan: ``ceil(log2 W)`` lane rotations, each masked by
+    a lane iota so nothing wraps around.  Integer adds are exact (and
+    wrap identically on overflow), so the result is bit-equal to
+    ``jnp.cumsum`` on the same row.
+    """
+    axis = x.ndim - 1
+    n = x.shape[axis]
+    lane = jax.lax.broadcasted_iota(jnp.int32, x.shape, axis)
+    shift = 1
+    while shift < n:
+        x = x + jnp.where(lane >= shift, pltpu.roll(x, shift, axis), 0)
+        shift *= 2
+    return x
+
+
+def _waterfill_kernel(target_ref, cap_ref, take_ref, carry_ref):
+    @pl.when(pl.program_id(0) == 0)
     def _init():
-        carry_ref[0] = jnp.int32(0)
+        carry_ref[...] = jnp.zeros_like(carry_ref)
 
     cap = cap_ref[...]                              # (1, blk) int32
-    prev = carry_ref[0]
-    cum = jnp.cumsum(cap, axis=-1)
-    before = prev + cum - cap                       # prefix sum before slot
-    remaining = target_ref[0] - before
+    prev = carry_ref[...]                           # (1, 1)
+    before = prev + lane_cumsum(cap) - cap          # prefix sum before slot
+    remaining = target_ref[...] - before
     take_ref[...] = jnp.clip(remaining, 0, cap)
-    carry_ref[0] = prev + cum[0, -1]
+    carry_ref[...] = prev + jnp.sum(cap, axis=-1, keepdims=True)
 
 
 def waterfill(capacity: jax.Array, target, *, block: int = 2048,
@@ -51,23 +65,28 @@ def waterfill(capacity: jax.Array, target, *, block: int = 2048,
     """
     cap = jnp.asarray(capacity, jnp.int32)
     n = cap.shape[0]
-    block = min(block, max(n, 1))
+    # lane-aligned block; zero-capacity padding takes nothing
+    block = min(block, max(-(-n // 128) * 128, 128))
     pad = (-n) % block
     if pad:
         cap = jnp.pad(cap, (0, pad))
     n_blocks = cap.shape[0] // block
-    cap2 = cap.reshape(n_blocks, block)
+    # (n_blocks, 1, block) with a squeezed block-index dim, so the block's
+    # last two dims equal the array's (Mosaic's tiling rule)
+    cap3 = cap.reshape(n_blocks, 1, block)
+    blk = pl.BlockSpec((None, 1, block), lambda i: (i, 0, 0))
 
     out = pl.pallas_call(
-        functools.partial(_waterfill_kernel, n_blocks=n_blocks),
+        _waterfill_kernel,
         grid=(n_blocks,),
-        in_specs=[pl.BlockSpec(memory_space=pltpu.SMEM),
-                  pl.BlockSpec((1, block), lambda i: (i, 0))],
-        out_specs=pl.BlockSpec((1, block), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct(cap2.shape, jnp.int32),
-        scratch_shapes=[pltpu.SMEM((1,), jnp.int32)],
+        # the target is a (1, 1) block too, so a vmapped call (one target
+        # per lane) keeps a tileable layout
+        in_specs=[pl.BlockSpec((1, 1), lambda i: (0, 0)), blk],
+        out_specs=blk,
+        out_shape=jax.ShapeDtypeStruct(cap3.shape, jnp.int32),
+        scratch_shapes=[pltpu.VMEM((1, 1), jnp.int32)],
         interpret=interpret,
-    )(jnp.asarray(target, jnp.int32).reshape(1), cap2)
+    )(jnp.asarray(target, jnp.int32).reshape(1, 1), cap3)
     return out.reshape(-1)[:n]
 
 

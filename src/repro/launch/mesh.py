@@ -45,7 +45,11 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"need {n} devices for mesh {shape}, have {len(devices)}; "
             "the dry-run must set XLA_FLAGS=--xla_force_host_platform_"
             "device_count=512 before importing jax")
-    return jax.make_mesh(shape, axes, devices=devices)
+    # Auto axes: the model code places activations with
+    # with_sharding_constraint, which make_mesh's default Explicit axes
+    # reject
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def dp_axes(mesh) -> tuple:

@@ -227,11 +227,8 @@ def apply_moe_sharded(p: Params, x: jax.Array, *, mesh, n_experts: int,
             aux = jax.lax.pmean(aux, dp)
         return out, aux
 
-    shard_map = getattr(jax, "shard_map", None)
-    if shard_map is None:  # jax < 0.5: only the experimental entry point
-        from jax.experimental.shard_map import shard_map
-    fn = shard_map(body, mesh=mesh, in_specs=(w_specs, x_spec),
-                   out_specs=(x_spec, P()))
+    fn = jax.shard_map(body, mesh=mesh, in_specs=(w_specs, x_spec),
+                       out_specs=(x_spec, P()))
     return fn(p, x)
 
 

@@ -207,6 +207,9 @@ def serve_http(engine: WhatIfEngine, host: str, port: int) -> int:
 def main(argv=None) -> int:
     args = build_parser().parse_args(argv)
     configure_observability(args)
+    if args.engine == "jax":
+        from repro.xla_cache import enable_compilation_cache
+        enable_compilation_cache()
     engine = engine_from_args(args)
 
     if args.http:

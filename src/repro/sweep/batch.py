@@ -484,12 +484,13 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
       compression, so they may ride in cell metrics without breaking
       execution-plan parity); ``steps, window, finished``; and
       execution-only observability scalars ``compile_s, execute_s,
-      compile_variants, retraces, warm_hits, escalations,
+      compile_variants, retraces, aot_rejits, warm_hits, escalations,
       compressed_events`` (wall-clock split by whether the chunk call
       paid a trace+compile, the distinct static chunk configurations this
       run dispatched — the compile-ladder width ``tools/check_perf.py``
       gates — the number of fresh foreground compile variants, warm AOT
-      executables used, window escalations, and per-lane events retired
+      executables that rejected their arguments and were re-jitted, warm
+      AOT executables used, window escalations, and per-lane events retired
       beyond the first of their scan step — these describe *this
       execution*, never the cells, and must stay out of metrics).
 
@@ -585,6 +586,7 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
     low_streak = 0
     escalations = 0
     retraces = 0
+    aot_rejits = 0
     warm_hits = 0
     compile_s = 0.0
     execute_s = 0.0
@@ -630,18 +632,15 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
             fn, is_warm = _WARM_EXECUTABLES[ckey], True
         elif ckey in _WARM_FUTURES:
             fut = _WARM_FUTURES.pop(ckey)
-            # blocking on an in-flight background compile is compile time
+            # blocking on an in-flight background compile is compile time;
+            # a failed one raises here — the foreground jit would only
+            # fail the same way, or hide what the device refused
             first = not fut.done()
-            try:
-                exe = fut.result()
-            except Exception:  # warm compile failed: fall back to jit
-                exe = None
-            if exe is not None:
-                _WARM_EXECUTABLES[ckey] = exe
-                _COMPILED_KEYS.add(ckey)
-                fn, is_warm = exe, True
-                warm_hits += 1
-                obs.counter("sweep.warm_hits")
+            fn = _WARM_EXECUTABLES[ckey] = fut.result()
+            _COMPILED_KEYS.add(ckey)
+            is_warm = True
+            warm_hits += 1
+            obs.counter("sweep.warm_hits")
         if fn is None:
             fn = fn_for(W)
             if ckey not in _COMPILED_KEYS:
@@ -660,11 +659,15 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
                 if not is_warm:
                     raise
                 # an AOT executable can reject its arguments at call time
-                # (e.g. sharded inputs); fall back to the jit path once
+                # (e.g. sharded inputs); re-jit once, counted and said
                 _WARM_EXECUTABLES.pop(ckey, None)
                 first = True
                 retraces += 1
+                aot_rejits += 1
                 obs.counter("sweep.retraces")
+                obs.counter("sweep.aot_rejits")
+                print(f"[sweep.batch] AOT executable for window W={W} "
+                      "rejected its arguments; re-jitted", flush=True)
                 out = fn_for(W)(batch, full, k, retrig, bf, nact, ncomp)
             full, k, retrig, bf, nact, ncomp, ys, all_done = out
             # host conversion blocks on the device work, so the span (and
@@ -700,6 +703,7 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
     out["execute_s"] = execute_s
     out["compile_variants"] = len(used_keys)
     out["retraces"] = retraces
+    out["aot_rejits"] = aot_rejits
     out["warm_hits"] = warm_hits
     out["escalations"] = escalations
     out["compressed_events"] = int(np.sum(np.asarray(ncomp)))
@@ -1002,3 +1006,29 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
                 (flat(ts), flat(busy), flat(qlen)), all_done)
 
     return run_chunk
+
+
+def chunk_arg_shapes(n: int, B: int, sharding=None) -> tuple:
+    """Abstract arguments of one :func:`_chunk_fn` call on ``B`` lanes of
+    ``n`` jobs — ``(batch, full, k, retrig, bf, nact, ncomp)`` as
+    ``jax.ShapeDtypeStruct`` — so a chunk program can be lowered or
+    compiled ahead of time without arrays (the TPU compile tests, the
+    chip smoke's kernel check)."""
+    def s(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    f32, i32, b = jnp.float32, jnp.int32, jnp.bool_
+    per_job = dict(submit=f32, malleable=b, min_nodes=i32, max_nodes=i32,
+                   pfrac=f32, inv_ref=f32, wall_work=f32, want=i32,
+                   floor=i32, shrink_floor=i32, prio_ref=i32, on_demand=b,
+                   pref_nodes=i32, sort_key=f32)
+    per_lane = dict(capacity=i32, tick=f32, backfill_depth=i32,
+                    pool_share=f32, steal_margin=i32)
+    batch = BatchedLanes(**{k: s((B, n), t) for k, t in per_job.items()},
+                         **{k: s((B,), t) for k, t in per_lane.items()})
+    full = dict(state=s((B, n), i32), alloc=s((B, n), i32),
+                remaining=s((B, n), f32), start_t=s((B, n), f32),
+                end_t=s((B, n), f32), expand_ops=s((B, n), i32),
+                shrink_ops=s((B, n), i32))
+    return (batch, full, s((B,), i32), s((B,), b), s((B,), i32),
+            s((B,), i32), s((B,), i32))

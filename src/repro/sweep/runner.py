@@ -9,9 +9,8 @@ the historical entry points alive:
     knobs included);
   * :func:`sweep_workload_jax` / :func:`sweep_workloads_jax` wrappers that
     build an :class:`repro.experiments.ExperimentSpec` and run it;
-  * :data:`CROSSCHECK_TOLERANCES` / :func:`enable_compilation_cache`
-    re-exports (now owned by ``repro.experiments.crosscheck`` and
-    ``repro.experiments.backend_jax``).
+  * the :data:`CROSSCHECK_TOLERANCES` re-export (now owned by
+    ``repro.experiments.crosscheck``).
 
 CLI::
 
@@ -30,7 +29,6 @@ from typing import Dict, Optional, Sequence
 from repro.core.strategies import (MALLEABLE_STRATEGY_NAMES,
                                    SWEEP_PROPORTIONS)
 from repro.experiments import ExperimentSpec, run_experiment
-from repro.experiments.backend_jax import enable_compilation_cache  # noqa: F401 (re-export)
 from repro.experiments.crosscheck import CROSSCHECK_TOLERANCES  # noqa: F401 (re-export)
 
 PROPORTIONS = SWEEP_PROPORTIONS

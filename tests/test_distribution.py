@@ -42,7 +42,8 @@ _EQUIV_SCRIPT = textwrap.dedent("""
     ref = T.forward_logits(params, cfg, batch, dtype=jnp.float32)
 
     # 4x2 (data, model) mesh
-    mesh = jax.make_mesh((4, 2), ("data", "model"))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     psh = jax.tree_util.tree_map(
         lambda s: NamedSharding(mesh, s), SH.param_specs(params, mesh))
     bsh = jax.tree_util.tree_map(

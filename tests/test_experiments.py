@@ -620,3 +620,43 @@ def test_compare_scenarios_categorical_axis(tmp_path, capsys):
     assert set(payload["results"]) == {"fcfs", "sjf"}
     for res in payload["results"].values():
         assert "rigid" in res["haswell"]
+
+
+# ----------------------------------------------------------------------
+# the persistent compilation cache: placed from outside, or a fixed path
+def test_xla_cache_env_var_stands_else_fixed_repo_path(monkeypatch,
+                                                       tmp_path):
+    import pathlib
+
+    import jax
+
+    from repro import xla_cache
+
+    repo = pathlib.Path(__file__).resolve().parents[1]
+    assert xla_cache.DEFAULT_DIR == repo / "artifacts" / "xla_cache"
+    updates = []
+    monkeypatch.setattr(jax.config, "update",
+                        lambda *a: updates.append(a))
+    monkeypatch.setenv(xla_cache.ENV_VAR, str(tmp_path / "outside"))
+    assert xla_cache.enable_compilation_cache() == tmp_path / "outside"
+    assert updates == []  # JAX's own setting stands
+    monkeypatch.delenv(xla_cache.ENV_VAR)
+    monkeypatch.setattr(xla_cache, "DEFAULT_DIR", tmp_path / "fixed")
+    assert xla_cache.enable_compilation_cache() == tmp_path / "fixed"
+    assert updates == [("jax_compilation_cache_dir",
+                        str(tmp_path / "fixed"))]
+
+
+def test_cold_xla_cache_never_deletes_the_env_directory(monkeypatch,
+                                                        tmp_path):
+    from benchmarks import run as bench_run
+
+    from repro import xla_cache
+
+    outside = tmp_path / "outside"
+    outside.mkdir()
+    (outside / "entry").write_text("compiled")
+    monkeypatch.setenv(xla_cache.ENV_VAR, str(outside))
+    with pytest.raises(SystemExit):
+        bench_run.main(["--cold-xla-cache", "--engine", "jax"])
+    assert (outside / "entry").exists()

@@ -553,292 +553,299 @@ def schedule_tick(p: PassParams, state, alloc, remaining, start_t, act,
     level_iters = int(math.ceil(math.log2(span_max + 2))) + 1
     od = p.on_demand if with_classes else None
 
-    running = state == RUNNING
-    free = capacity - jnp.sum(jnp.where(running, alloc, 0), axis=-1)
+    with jax.named_scope("sched.fcfs"):
+        running = state == RUNNING
+        free = capacity - jnp.sum(jnp.where(running, alloc, 0), axis=-1)
 
-    # -- Step 1: FCFS prefix (slots are in FCFS order) --------------------
-    queued = (state == QUEUED) & act
-    if with_classes:
-        # class-priority prefix: queued on-demand slots start first (in
-        # submit order); non-on-demand slots may only join the prefix when
-        # every queued on-demand job started.
-        q_od = queued & od
-        cumw_od = jnp.cumsum(jnp.where(q_od, p.want, 0), axis=-1)
-        s1o = q_od & (cumw_od <= free[..., None])
-        used_od = jnp.max(jnp.where(s1o, cumw_od, 0), axis=-1)
-        all_od = ~jnp.any(q_od & ~s1o, axis=-1)
-        rem = free - used_od
-        q_n = queued & ~od
-        cumw_n = jnp.cumsum(jnp.where(q_n, p.want, 0), axis=-1)
-        s1 = s1o | (q_n & (cumw_n <= rem[..., None]) & all_od[..., None])
-        leftover = rem - jnp.max(
-            jnp.where(s1 & ~od, cumw_n, 0), axis=-1)
-        h_mask = priority_head(queued & ~s1, od)
-    else:
-        cumw = jnp.cumsum(jnp.where(queued, p.want, 0), axis=-1)
-        s1 = queued & (cumw <= free[..., None])
-        used = jnp.max(jnp.where(s1, cumw, 0), axis=-1)
-        leftover = free - used
-        # head fallback: first queued job not started, floor fits leftover
-        h_mask = first_true(queued & ~s1)
-    hfloor = jnp.sum(jnp.where(h_mask, p.floor, 0), axis=-1)
-    hwant = jnp.sum(jnp.where(h_mask, p.want, 0), axis=-1)
-    h_ok = (hfloor > 0) & (hfloor <= leftover)  # floor >= 1 on real jobs
-    h_alloc = jnp.clip(leftover, hfloor, hwant)
-
-    h_upd = h_mask & h_ok[..., None]
-    started = s1 | h_upd
-    alloc = jnp.where(s1, p.want, alloc)
-    alloc = jnp.where(h_upd, h_alloc[..., None], alloc)
-    state = jnp.where(started, RUNNING, state)
-    start_t = jnp.where(started, t_now[..., None], start_t)
-    free = leftover - jnp.where(h_ok, h_alloc, 0)
-
-    # -- EASY backfill under the head's shadow-time reservation -----------
-    queued = (state == QUEUED) & act
-    h_mask = priority_head(queued, od) if with_classes else \
-        first_true(queued)
-    hfloor = jnp.sum(jnp.where(h_mask, p.floor, 0), axis=-1)
-    hwant = jnp.sum(jnp.where(h_mask, p.want, 0), axis=-1)
-    has_head = hfloor > 0
-
-    def backfill(args):
-        state, alloc, start_t, free = args
-        if backfill_depth is None:
-            depth_ok = True
+        # -- Step 1: FCFS prefix (slots are in FCFS order) --------------------
+        queued = (state == QUEUED) & act
+        if with_classes:
+            # class-priority prefix: queued on-demand slots start first (in
+            # submit order); non-on-demand slots may only join the prefix when
+            # every queued on-demand job started.
+            q_od = queued & od
+            cumw_od = jnp.cumsum(jnp.where(q_od, p.want, 0), axis=-1)
+            s1o = q_od & (cumw_od <= free[..., None])
+            used_od = jnp.max(jnp.where(s1o, cumw_od, 0), axis=-1)
+            all_od = ~jnp.any(q_od & ~s1o, axis=-1)
+            rem = free - used_od
+            q_n = queued & ~od
+            cumw_n = jnp.cumsum(jnp.where(q_n, p.want, 0), axis=-1)
+            s1 = s1o | (q_n & (cumw_n <= rem[..., None]) & all_od[..., None])
+            leftover = rem - jnp.max(
+                jnp.where(s1 & ~od, cumw_n, 0), axis=-1)
+            h_mask = priority_head(queued & ~s1, od)
         else:
-            # rank cutoff over the queue snapshot at scan entry: the head
-            # holds rank 1, so candidates 1..depth behind it are ranks
-            # 2..depth+1 (the DES's ``queue[1 : 1 + depth]`` slice)
-            ranks = queue_ranks((state == QUEUED) & act, od)
-            depth_ok = ranks <= backfill_depth[..., None] + 1
-        run = state == RUNNING
-        est = jnp.where(
-            run,
-            t_now[..., None] + remaining * p.wall_work
-            / _speedup_f32(alloc, p.pfrac),
-            INF)
-        sh_b, ex_b = shadow_reservation(est, alloc, free, hfloor,
-                                        iters=shadow_iters)
-        blocked = has_head & (hfloor > free)
-        # head fits free: reservation starts now; no head: unconstrained
-        shadow = jnp.where(blocked, sh_b, jnp.where(has_head, t_now, INF))
-        extra = jnp.where(blocked, ex_b,
-                          jnp.where(has_head, free - hfloor, free))
+            cumw = jnp.cumsum(jnp.where(queued, p.want, 0), axis=-1)
+            s1 = queued & (cumw <= free[..., None])
+            used = jnp.max(jnp.where(s1, cumw, 0), axis=-1)
+            leftover = free - used
+            # head fallback: first queued job not started, floor fits leftover
+            h_mask = first_true(queued & ~s1)
+        hfloor = jnp.sum(jnp.where(h_mask, p.floor, 0), axis=-1)
+        hwant = jnp.sum(jnp.where(h_mask, p.want, 0), axis=-1)
+        h_ok = (hfloor > 0) & (hfloor <= leftover)  # floor >= 1 on real jobs
+        h_alloc = jnp.clip(leftover, hfloor, hwant)
 
-        def qcumsum(amount, mask):
-            return queue_cumsum(amount, mask, od)
+        h_upd = h_mask & h_ok[..., None]
+        started = s1 | h_upd
+        alloc = jnp.where(s1, p.want, alloc)
+        alloc = jnp.where(h_upd, h_alloc[..., None], alloc)
+        state = jnp.where(started, RUNNING, state)
+        start_t = jnp.where(started, t_now[..., None], start_t)
+        free = leftover - jnp.where(h_ok, h_alloc, 0)
 
-        tfit = t_now[..., None] + p.wall_work / _speedup_f32(
-            p.want, p.pfrac) <= shadow[..., None] + _SHADOW_EPS
-        for _ in range(fill_rounds):
-            cand = (state == QUEUED) & act & ~h_mask & depth_ok
-            # (a) finishes before the reservation: free nodes only
-            c = cand & tfit & (p.want <= free[..., None])
-            cum = qcumsum(p.want, c)
-            s = c & (cum <= free[..., None])
-            free = free - jnp.max(jnp.where(s, cum, 0), axis=-1)
-            # (b) runs past the reservation: spare-node pool, at want
-            lim = jnp.minimum(free, extra)
-            c2 = cand & ~s & ~tfit & (p.want <= lim[..., None])
-            cum2 = qcumsum(p.want, c2)
-            s2 = c2 & (cum2 <= lim[..., None])
-            take2 = jnp.max(jnp.where(s2, cum2, 0), axis=-1)
-            # (c) spare-node pool at floor (want did not fit)
-            lim3 = jnp.minimum(free - take2, extra - take2)
-            c3 = cand & ~s & ~s2 & ~tfit & (p.floor <= lim3[..., None])
-            cum3 = qcumsum(p.floor, c3)
-            s3 = c3 & (cum3 <= lim3[..., None])
-            take3 = jnp.max(jnp.where(s3, cum3, 0), axis=-1)
+    with jax.named_scope("sched.backfill"):
+        # -- EASY backfill under the head's shadow-time reservation -----------
+        queued = (state == QUEUED) & act
+        h_mask = priority_head(queued, od) if with_classes else \
+            first_true(queued)
+        hfloor = jnp.sum(jnp.where(h_mask, p.floor, 0), axis=-1)
+        hwant = jnp.sum(jnp.where(h_mask, p.want, 0), axis=-1)
+        has_head = hfloor > 0
 
-            free = free - take2 - take3
-            extra = extra - take2 - take3
-            new = s | s2 | s3
-            alloc = jnp.where(s | s2, p.want, jnp.where(s3, p.floor, alloc))
-            state = jnp.where(new, RUNNING, state)
-            start_t = jnp.where(new, t_now[..., None], start_t)
-        return state, alloc, start_t, free
+        def backfill(args):
+            state, alloc, start_t, free = args
+            if backfill_depth is None:
+                depth_ok = True
+            else:
+                # rank cutoff over the queue snapshot at scan entry: the head
+                # holds rank 1, so candidates 1..depth behind it are ranks
+                # 2..depth+1 (the DES's ``queue[1 : 1 + depth]`` slice)
+                ranks = queue_ranks((state == QUEUED) & act, od)
+                depth_ok = ranks <= backfill_depth[..., None] + 1
+            run = state == RUNNING
+            est = jnp.where(
+                run,
+                t_now[..., None] + remaining * p.wall_work
+                / _speedup_f32(alloc, p.pfrac),
+                INF)
+            sh_b, ex_b = shadow_reservation(est, alloc, free, hfloor,
+                                            iters=shadow_iters)
+            blocked = has_head & (hfloor > free)
+            # head fits free: reservation starts now; no head: unconstrained
+            shadow = jnp.where(blocked, sh_b, jnp.where(has_head, t_now, INF))
+            extra = jnp.where(blocked, ex_b,
+                              jnp.where(has_head, free - hfloor, free))
 
-    state, alloc, start_t, free = jax.lax.cond(
-        jnp.any(has_head), backfill, lambda a: a,
-        (state, alloc, start_t, free))
+            def qcumsum(amount, mask):
+                return queue_cumsum(amount, mask, od)
 
-    # -- Step 2: shrink running malleable jobs to admit the head ----------
-    deficit = jnp.where(has_head, hfloor - free, 0)
-    shrinkable = (state == RUNNING) & p.malleable
-    fl = jnp.where(shrinkable, jnp.minimum(p.shrink_floor, alloc), alloc)
-    surplus = jnp.maximum(alloc - fl, 0)
-    tot_surplus = jnp.sum(surplus, axis=-1)
-    need = jnp.where((deficit > 0) & (tot_surplus >= deficit), deficit, 0)
+            tfit = t_now[..., None] + p.wall_work / _speedup_f32(
+                p.want, p.pfrac) <= shadow[..., None] + _SHADOW_EPS
+            for _ in range(fill_rounds):
+                cand = (state == QUEUED) & act & ~h_mask & depth_ok
+                # (a) finishes before the reservation: free nodes only
+                c = cand & tfit & (p.want <= free[..., None])
+                cum = qcumsum(p.want, c)
+                s = c & (cum <= free[..., None])
+                free = free - jnp.max(jnp.where(s, cum, 0), axis=-1)
+                # (b) runs past the reservation: spare-node pool, at want
+                lim = jnp.minimum(free, extra)
+                c2 = cand & ~s & ~tfit & (p.want <= lim[..., None])
+                cum2 = qcumsum(p.want, c2)
+                s2 = c2 & (cum2 <= lim[..., None])
+                take2 = jnp.max(jnp.where(s2, cum2, 0), axis=-1)
+                # (c) spare-node pool at floor (want did not fit)
+                lim3 = jnp.minimum(free - take2, extra - take2)
+                c3 = cand & ~s & ~s2 & ~tfit & (p.floor <= lim3[..., None])
+                cum3 = qcumsum(p.floor, c3)
+                s3 = c3 & (cum3 <= lim3[..., None])
+                take3 = jnp.max(jnp.where(s3, cum3, 0), axis=-1)
 
-    prio = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
+                free = free - take2 - take3
+                extra = extra - take2 - take3
+                new = s | s2 | s3
+                alloc = jnp.where(s | s2, p.want,
+                                  jnp.where(s3, p.floor, alloc))
+                state = jnp.where(new, RUNNING, state)
+                start_t = jnp.where(new, t_now[..., None], start_t)
+            return state, alloc, start_t, free
 
-    if balanced:
-        def shrink(alloc):
-            mn_eff = jnp.where(shrinkable, fl, alloc)
-            mx_eff = jnp.where(shrinkable, p.max_nodes, alloc)
-            lanes = need.shape
-            lo = jnp.zeros(lanes, jnp.float32)
-            hi = jnp.ones(lanes, jnp.float32)
-            freed_lo = tot_surplus
-            for _ in range(level_iters):
-                mid = 0.5 * (lo + hi)
+        state, alloc, start_t, free = jax.lax.cond(
+            jnp.any(has_head), backfill, lambda a: a,
+            (state, alloc, start_t, free))
+
+    with jax.named_scope("sched.shrink"):
+        # -- Step 2: shrink running malleable jobs to admit the head ----------
+        deficit = jnp.where(has_head, hfloor - free, 0)
+        shrinkable = (state == RUNNING) & p.malleable
+        fl = jnp.where(shrinkable, jnp.minimum(p.shrink_floor, alloc), alloc)
+        surplus = jnp.maximum(alloc - fl, 0)
+        tot_surplus = jnp.sum(surplus, axis=-1)
+        need = jnp.where((deficit > 0) & (tot_surplus >= deficit), deficit, 0)
+
+        prio = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
+
+        if balanced:
+            def shrink(alloc):
+                mn_eff = jnp.where(shrinkable, fl, alloc)
+                mx_eff = jnp.where(shrinkable, p.max_nodes, alloc)
+                lanes = need.shape
+                lo = jnp.zeros(lanes, jnp.float32)
+                hi = jnp.ones(lanes, jnp.float32)
+                freed_lo = tot_surplus
+                for _ in range(level_iters):
+                    mid = 0.5 * (lo + hi)
+                    tgt = jnp.minimum(
+                        alloc, level_targets(mid[..., None], mn_eff, mx_eff))
+                    freed = jnp.sum(alloc - tgt, axis=-1)
+                    ok = freed >= need
+                    lo = jnp.where(ok, mid, lo)
+                    hi = jnp.where(ok, hi, mid)
+                    freed_lo = jnp.where(ok, freed, freed_lo)
                 tgt = jnp.minimum(
-                    alloc, level_targets(mid[..., None], mn_eff, mx_eff))
-                freed = jnp.sum(alloc - tgt, axis=-1)
-                ok = freed >= need
-                lo = jnp.where(ok, mid, lo)
-                hi = jnp.where(ok, hi, mid)
-                freed_lo = jnp.where(ok, freed, freed_lo)
-            tgt = jnp.minimum(
-                alloc, level_targets(lo[..., None], mn_eff, mx_eff))
-            # return integer-rounding excess to the most-shrunk jobs
-            delta = alloc - tgt
-            give = give_asc_prefix(-delta, delta, freed_lo - need,
-                                   -span_max - 1, 0)
-            return alloc - (delta - give)
-    else:
-        def shrink(alloc):
-            return alloc - take_desc_prefix(prio, surplus, need,
-                                            prio_lo - 1, prio_hi)
+                    alloc, level_targets(lo[..., None], mn_eff, mx_eff))
+                # return integer-rounding excess to the most-shrunk jobs
+                delta = alloc - tgt
+                give = give_asc_prefix(-delta, delta, freed_lo - need,
+                                       -span_max - 1, 0)
+                return alloc - (delta - give)
+        else:
+            def shrink(alloc):
+                return alloc - take_desc_prefix(prio, surplus, need,
+                                                prio_lo - 1, prio_hi)
 
-    alloc = jax.lax.cond(jnp.any(need > 0), shrink, lambda a: a, alloc)
-    free = free + need  # the take sums to exactly `need` by construction
+        alloc = jax.lax.cond(jnp.any(need > 0), shrink, lambda a: a, alloc)
+        free = free + need  # the take sums to exactly `need` by construction
 
-    h_ok = has_head & (hfloor <= free)
-    h_alloc = jnp.clip(free, hfloor, hwant)
-    h_upd = h_mask & h_ok[..., None]
-    alloc = jnp.where(h_upd, h_alloc[..., None], alloc)
-    state = jnp.where(h_upd, RUNNING, state)
-    start_t = jnp.where(h_upd, t_now[..., None], start_t)
-    free = free - jnp.where(h_ok, h_alloc, 0)
+        h_ok = has_head & (hfloor <= free)
+        h_alloc = jnp.clip(free, hfloor, hwant)
+        h_upd = h_mask & h_ok[..., None]
+        alloc = jnp.where(h_upd, h_alloc[..., None], alloc)
+        state = jnp.where(h_upd, RUNNING, state)
+        start_t = jnp.where(h_upd, t_now[..., None], start_t)
+        free = free - jnp.where(h_ok, h_alloc, 0)
 
-    # -- Step 2b: structure-specific extra pass ---------------------------
-    if structure == "pooled":
-        # Common-pool start pass (docs/strategies.md § pref_common_pool):
-        # running malleable jobs' surplus above their preferred
-        # allocation forms a shared pool; queued malleable candidates
-        # behind the head draw their floor from it in queue order
-        # (prefix semantics: the first non-fitting malleable candidate
-        # blocks the rest, like the DES scan).  The pool never touches
-        # free nodes, so the head's shadow reservation is unaffected,
-        # and every pool start is paid for by shrinking donors back
-        # toward preferred — busy is conserved by construction.
-        run_m = (state == RUNNING) & p.malleable
-        over_pref = jnp.where(run_m,
-                              jnp.maximum(alloc - p.pref_nodes, 0), 0)
-        pool_amt = jnp.sum(over_pref, axis=-1)
-        share = pool_share if pool_share is not None else 1.0
-        budget = jnp.minimum((share * pool_amt).astype(pool_amt.dtype),
-                             pool_amt)
-        q_pool = (state == QUEUED) & act
-        h_pool = priority_head(q_pool, od) if with_classes else \
-            first_true(q_pool)
-        cand = q_pool & p.malleable & ~h_pool
-        cumf = queue_cumsum(p.floor, cand, od)
-        sp = cand & (cumf <= budget[..., None])
-        taken = jnp.max(jnp.where(sp, cumf, 0), axis=-1)
+    with jax.named_scope("sched.extra"):
+        # -- Step 2b: structure-specific extra pass ---------------------------
+        if structure == "pooled":
+            # Common-pool start pass (docs/strategies.md § pref_common_pool):
+            # running malleable jobs' surplus above their preferred
+            # allocation forms a shared pool; queued malleable candidates
+            # behind the head draw their floor from it in queue order
+            # (prefix semantics: the first non-fitting malleable candidate
+            # blocks the rest, like the DES scan).  The pool never touches
+            # free nodes, so the head's shadow reservation is unaffected,
+            # and every pool start is paid for by shrinking donors back
+            # toward preferred — busy is conserved by construction.
+            run_m = (state == RUNNING) & p.malleable
+            over_pref = jnp.where(run_m,
+                                  jnp.maximum(alloc - p.pref_nodes, 0), 0)
+            pool_amt = jnp.sum(over_pref, axis=-1)
+            share = pool_share if pool_share is not None else 1.0
+            budget = jnp.minimum((share * pool_amt).astype(pool_amt.dtype),
+                                 pool_amt)
+            q_pool = (state == QUEUED) & act
+            h_pool = priority_head(q_pool, od) if with_classes else \
+                first_true(q_pool)
+            cand = q_pool & p.malleable & ~h_pool
+            cumf = queue_cumsum(p.floor, cand, od)
+            sp = cand & (cumf <= budget[..., None])
+            taken = jnp.max(jnp.where(sp, cumf, 0), axis=-1)
 
-        def pool_start(args):
-            state, alloc, start_t = args
-            pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
-            take = take_desc_prefix(pr, over_pref, taken,
-                                    prio_lo - 1, prio_hi)
-            alloc = alloc - take
-            alloc = jnp.where(sp, p.floor, alloc)
-            state = jnp.where(sp, RUNNING, state)
-            start_t = jnp.where(sp, t_now[..., None], start_t)
-            return state, alloc, start_t
+            def pool_start(args):
+                state, alloc, start_t = args
+                pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
+                take = take_desc_prefix(pr, over_pref, taken,
+                                        prio_lo - 1, prio_hi)
+                alloc = alloc - take
+                alloc = jnp.where(sp, p.floor, alloc)
+                state = jnp.where(sp, RUNNING, state)
+                start_t = jnp.where(sp, t_now[..., None], start_t)
+                return state, alloc, start_t
 
-        state, alloc, start_t = jax.lax.cond(
-            jnp.any(taken > 0), pool_start, lambda a: a,
-            (state, alloc, start_t))
+            state, alloc, start_t = jax.lax.cond(
+                jnp.any(taken > 0), pool_start, lambda a: a,
+                (state, alloc, start_t))
 
-    if structure == "stealing":
-        # Steal-agreement pass (docs/strategies.md § steal_agreement):
-        # running malleable jobs above the average running allocation
-        # (plus the per-lane steal margin) donate their surplus above
-        # max(average, shrink floor); starved under-average jobs steal
-        # up to min(average, max_nodes).  The transfer is min(donatable,
-        # stealable), taken highest-priority-first and given
-        # lowest-priority-first — busy is conserved, and repeated
-        # application converges (donors land on the average).
-        run_m = (state == RUNNING) & p.malleable
-        n_run = jnp.sum(run_m, axis=-1)
-        avg = (jnp.sum(jnp.where(run_m, alloc, 0), axis=-1)
-               // jnp.maximum(n_run, 1))
-        margin = steal_margin if steal_margin is not None else 0
-        sfl = jnp.where(run_m, jnp.minimum(p.shrink_floor, alloc), alloc)
-        donor = run_m & (alloc > (avg + margin)[..., None])
-        donor_amt = jnp.where(
-            donor,
-            jnp.maximum(alloc - jnp.maximum(avg[..., None], sfl), 0), 0)
-        taker_room = jnp.where(
-            run_m,
-            jnp.maximum(jnp.minimum(avg[..., None], p.max_nodes) - alloc,
-                        0), 0)
-        transfer = jnp.minimum(jnp.sum(donor_amt, axis=-1),
-                               jnp.sum(taker_room, axis=-1))
+        if structure == "stealing":
+            # Steal-agreement pass (docs/strategies.md § steal_agreement):
+            # running malleable jobs above the average running allocation
+            # (plus the per-lane steal margin) donate their surplus above
+            # max(average, shrink floor); starved under-average jobs steal
+            # up to min(average, max_nodes).  The transfer is min(donatable,
+            # stealable), taken highest-priority-first and given
+            # lowest-priority-first — busy is conserved, and repeated
+            # application converges (donors land on the average).
+            run_m = (state == RUNNING) & p.malleable
+            n_run = jnp.sum(run_m, axis=-1)
+            avg = (jnp.sum(jnp.where(run_m, alloc, 0), axis=-1)
+                   // jnp.maximum(n_run, 1))
+            margin = steal_margin if steal_margin is not None else 0
+            sfl = jnp.where(run_m, jnp.minimum(p.shrink_floor, alloc), alloc)
+            donor = run_m & (alloc > (avg + margin)[..., None])
+            donor_amt = jnp.where(
+                donor,
+                jnp.maximum(alloc - jnp.maximum(avg[..., None], sfl), 0), 0)
+            taker_room = jnp.where(
+                run_m,
+                jnp.maximum(jnp.minimum(avg[..., None], p.max_nodes) - alloc,
+                            0), 0)
+            transfer = jnp.minimum(jnp.sum(donor_amt, axis=-1),
+                                   jnp.sum(taker_room, axis=-1))
 
-        def steal(alloc):
-            pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
-            take = take_desc_prefix(pr, donor_amt, transfer,
-                                    prio_lo - 1, prio_hi)
-            give = give_asc_prefix(pr, taker_room, transfer,
-                                   prio_lo - 1, prio_hi)
-            return alloc - take + give
+            def steal(alloc):
+                pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
+                take = take_desc_prefix(pr, donor_amt, transfer,
+                                        prio_lo - 1, prio_hi)
+                give = give_asc_prefix(pr, taker_room, transfer,
+                                       prio_lo - 1, prio_hi)
+                return alloc - take + give
 
-        alloc = jax.lax.cond(jnp.any(transfer > 0), steal, lambda a: a,
-                             alloc)
+            alloc = jax.lax.cond(jnp.any(transfer > 0), steal, lambda a: a,
+                                 alloc)
 
-    # -- Step 3: expand into remaining idle nodes -------------------------
-    expandable = (state == RUNNING) & p.malleable
-    idle = jnp.maximum(
-        jnp.where(jnp.any(expandable, axis=-1), free, 0), 0)
-    if balanced:
-        def expand(alloc):
-            mn_eff = jnp.where(expandable, p.min_nodes, alloc)
-            cap_eff = jnp.where(expandable, p.max_nodes, alloc)
-            room_tot = jnp.sum(jnp.maximum(cap_eff - alloc, 0), axis=-1)
-            idle_eff = jnp.minimum(idle, room_tot)
-            lanes = idle.shape
-            lo = jnp.zeros(lanes, jnp.float32)
-            hi = jnp.ones(lanes, jnp.float32)
-            used_lo = jnp.zeros_like(idle_eff)
-            for _ in range(level_iters):
-                mid = 0.5 * (lo + hi)
+    with jax.named_scope("sched.expand"):
+        # -- Step 3: expand into remaining idle nodes -------------------------
+        expandable = (state == RUNNING) & p.malleable
+        idle = jnp.maximum(
+            jnp.where(jnp.any(expandable, axis=-1), free, 0), 0)
+        if balanced:
+            def expand(alloc):
+                mn_eff = jnp.where(expandable, p.min_nodes, alloc)
+                cap_eff = jnp.where(expandable, p.max_nodes, alloc)
+                room_tot = jnp.sum(jnp.maximum(cap_eff - alloc, 0), axis=-1)
+                idle_eff = jnp.minimum(idle, room_tot)
+                lanes = idle.shape
+                lo = jnp.zeros(lanes, jnp.float32)
+                hi = jnp.ones(lanes, jnp.float32)
+                used_lo = jnp.zeros_like(idle_eff)
+                for _ in range(level_iters):
+                    mid = 0.5 * (lo + hi)
+                    tgt = jnp.maximum(alloc, jnp.minimum(
+                        level_targets(mid[..., None], mn_eff, cap_eff),
+                        cap_eff))
+                    spent = jnp.sum(tgt - alloc, axis=-1)
+                    ok = spent <= idle_eff
+                    lo = jnp.where(ok, mid, lo)
+                    hi = jnp.where(ok, hi, mid)
+                    used_lo = jnp.where(ok, spent, used_lo)
                 tgt = jnp.maximum(alloc, jnp.minimum(
-                    level_targets(mid[..., None], mn_eff, cap_eff), cap_eff))
-                spent = jnp.sum(tgt - alloc, axis=-1)
-                ok = spent <= idle_eff
-                lo = jnp.where(ok, mid, lo)
-                hi = jnp.where(ok, hi, mid)
-                used_lo = jnp.where(ok, spent, used_lo)
-            tgt = jnp.maximum(alloc, jnp.minimum(
-                level_targets(lo[..., None], mn_eff, cap_eff), cap_eff))
-            # hand the leftover to the least-utilized jobs (2^-16 levels)
-            span = jnp.maximum(cap_eff - mn_eff, 1)
-            balance_q = ((tgt - mn_eff) * 65536) // span
-            room = jnp.maximum(cap_eff - tgt, 0)
-            give = give_asc_prefix(balance_q, room, idle_eff - used_lo,
-                                   -1, 65537)
-            return tgt + give
-    else:
-        def expand(alloc):
-            room = jnp.where(expandable,
-                             jnp.maximum(p.max_nodes - alloc, 0), 0)
-            pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
-            if expand_backend in ("pallas", "pallas-interpret"):
-                give = _pallas_give(pr, room, idle,
-                                    interpret=expand_backend
-                                    == "pallas-interpret")
-            else:  # bisect, and the fused backends' unfused statics
-                give = give_asc_prefix(pr, room, idle, prio_lo - 1, prio_hi)
-            return alloc + give
+                    level_targets(lo[..., None], mn_eff, cap_eff), cap_eff))
+                # hand the leftover to the least-utilized jobs (2^-16 levels)
+                span = jnp.maximum(cap_eff - mn_eff, 1)
+                balance_q = ((tgt - mn_eff) * 65536) // span
+                room = jnp.maximum(cap_eff - tgt, 0)
+                give = give_asc_prefix(balance_q, room, idle_eff - used_lo,
+                                       -1, 65537)
+                return tgt + give
+        else:
+            def expand(alloc):
+                room = jnp.where(expandable,
+                                 jnp.maximum(p.max_nodes - alloc, 0), 0)
+                pr = jnp.clip(alloc - p.prio_ref, prio_lo, prio_hi)
+                if expand_backend in ("pallas", "pallas-interpret"):
+                    give = _pallas_give(pr, room, idle,
+                                        interpret=expand_backend
+                                        == "pallas-interpret")
+                else:  # bisect, and the fused backends' unfused statics
+                    give = give_asc_prefix(pr, room, idle, prio_lo - 1,
+                                           prio_hi)
+                return alloc + give
 
-    return (state,
-            jax.lax.cond(jnp.any(idle > 0), expand, lambda a: a, alloc),
-            start_t)
+        alloc = jax.lax.cond(jnp.any(idle > 0), expand, lambda a: a, alloc)
+    return state, alloc, start_t
 
 
 def _pallas_give(prio, room, idle, *, interpret: bool):

@@ -108,23 +108,25 @@ def run_cells(spec: ExperimentSpec,
     for structure, group in groups.items():
         if not group:
             continue
-        batches, t0s, t1s, caps = [], [], [], []
-        for name in names:
-            lanes = [(get_strategy(s), p, sd)
-                     for wname, (s, p, sd) in group if wname == name]
-            if not lanes:
-                continue
-            cl, w_rigid, window = wls[name]
-            batch, _order = build_lanes(
-                w_rigid, cl.nodes, lanes, config=spec.transform,
-                tick=cl.tick,
-                backfill_depth=spec.scenario.backfill_depth,
-                queue_order=spec.scenario.queue_order)
-            batches.append(batch)
-            t0s += [window.t0] * len(lanes)
-            t1s += [window.t1] * len(lanes)
-            caps += [cl.nodes] * len(lanes)
-        big = concat_lanes(batches) if len(batches) > 1 else batches[0]
+        with obs.span("experiment.lanes", structure=structure,
+                      lanes=len(group)):
+            batches, t0s, t1s, caps = [], [], [], []
+            for name in names:
+                lanes = [(get_strategy(s), p, sd)
+                         for wname, (s, p, sd) in group if wname == name]
+                if not lanes:
+                    continue
+                cl, w_rigid, window = wls[name]
+                batch, _order = build_lanes(
+                    w_rigid, cl.nodes, lanes, config=spec.transform,
+                    tick=cl.tick,
+                    backfill_depth=spec.scenario.backfill_depth,
+                    queue_order=spec.scenario.queue_order)
+                batches.append(batch)
+                t0s += [window.t0] * len(lanes)
+                t1s += [window.t1] * len(lanes)
+                caps += [cl.nodes] * len(lanes)
+            big = concat_lanes(batches) if len(batches) > 1 else batches[0]
         win0, win1 = np.asarray(t0s), np.asarray(t1s)
         caps_arr = np.asarray(caps)
         cfg = EngineConfig(structure=structure,
@@ -150,68 +152,70 @@ def run_cells(spec: ExperimentSpec,
         steps_total, window_peak, budget_cut = 0, 0, False
         variants_peak = 0  # chunks of one structure share compile keys
         for ch in simulate_lanes_chunked(big, cfg, shard, verbose=verbose):
-            res = ch.results
-            per_lane = batched_metrics(
-                res, big.submit[ch.lo:ch.hi], big.malleable[ch.lo:ch.hi],
-                (win0[ch.lo:ch.hi], win1[ch.lo:ch.hi]),
-                caps_arr[ch.lo:ch.hi])
-            # device-accumulated per-lane scheduling counters ride in the
-            # metric dicts (execution-plan-invariant; never fingerprinted)
-            shrink_ev = np.sum(res["shrink_ops"], axis=1)
-            expand_ev = np.sum(res["expand_ops"], axis=1)
-            for i, m in enumerate(per_lane):
-                m["sched_backfill_starts"] = float(res["bf_starts"][i])
-                m["sched_shrink_events"] = float(shrink_ev[i])
-                m["sched_expand_events"] = float(expand_ev[i])
-                m["sched_invocations"] = float(res["sched_steps"][i])
-            # only completed lanes enter the persistent store: a lane cut
-            # off by the step budget has partial metrics that must not be
-            # replayed.  The flush happens before the next chunk runs, so
-            # an interrupted stream resumes from the last finished chunk.
-            lane_done = np.all(res["state"] == DONE, axis=1)
-            flushed = 0
-            # group is workload-major, matching the per-name lane stacking
-            for key, m, done in zip(group[ch.lo:ch.hi], per_lane,
-                                    lane_done):
-                metrics[key] = m
-                if bool(done):
-                    if store is not None:
-                        store.put(fingerprints[key], m)
-                        flushed += 1
-                else:
-                    info["incomplete"].append(key)
-            steps_total += int(res["steps"])
-            window_peak = max(window_peak, int(res["window"]))
-            budget_cut = budget_cut or not res["finished"]
-            info["chunks"].append({
-                "structure": tag, "lanes": ch.hi - ch.lo,
-                "lane_width": ch.lane_width, "devices": ch.n_devices,
-                "wall_s": ch.wall_s, "steps": int(res["steps"]),
-                "window": int(res["window"]),
-                "compile_s": float(res["compile_s"]),
-                "execute_s": float(res["execute_s"]),
-                "compile_variants": int(res.get("compile_variants", 0)),
-                "retraces": int(res["retraces"]),
-                "aot_rejits": int(res["aot_rejits"]),
-                "escalations": int(res["escalations"]),
-                "warm_hits": int(res["warm_hits"]),
-                "sched_steps": int(np.sum(res["sched_steps"])),
-                "compressed_events": int(res["compressed_events"]),
-            })
-            info["compile_s"] += float(res["compile_s"])
-            info["execute_s"] += float(res["execute_s"])
-            variants_peak = max(variants_peak,
-                                int(res.get("compile_variants", 0)))
-            info["retraces"] += int(res["retraces"])
-            info["aot_rejits"] += int(res["aot_rejits"])
-            info["escalations"] += int(res["escalations"])
-            info["warm_hits"] += int(res["warm_hits"])
-            info["sched_steps"] += int(np.sum(res["sched_steps"]))
-            info["compressed_events"] += int(res["compressed_events"])
-            info["peak_lane_width"] = max(info["peak_lane_width"],
-                                          ch.lane_width)
-            info["devices"] = ch.n_devices
-            heartbeat.tick(cells_flushed=flushed)
+            with obs.span("experiment.metrics", structure=structure,
+                          lanes=ch.hi - ch.lo):
+                res = ch.results
+                per_lane = batched_metrics(
+                    res, big.submit[ch.lo:ch.hi], big.malleable[ch.lo:ch.hi],
+                    (win0[ch.lo:ch.hi], win1[ch.lo:ch.hi]),
+                    caps_arr[ch.lo:ch.hi])
+                # device-accumulated per-lane scheduling counters ride in the
+                # metric dicts (execution-plan-invariant; never fingerprinted)
+                shrink_ev = np.sum(res["shrink_ops"], axis=1)
+                expand_ev = np.sum(res["expand_ops"], axis=1)
+                for i, m in enumerate(per_lane):
+                    m["sched_backfill_starts"] = float(res["bf_starts"][i])
+                    m["sched_shrink_events"] = float(shrink_ev[i])
+                    m["sched_expand_events"] = float(expand_ev[i])
+                    m["sched_invocations"] = float(res["sched_steps"][i])
+                # only completed lanes enter the persistent store: a lane cut
+                # off by the step budget has partial metrics that must not be
+                # replayed.  The flush happens before the next chunk runs, so
+                # an interrupted stream resumes from the last finished chunk.
+                lane_done = np.all(res["state"] == DONE, axis=1)
+                flushed = 0
+                # group is workload-major, matching the per-name lane stacking
+                for key, m, done in zip(group[ch.lo:ch.hi], per_lane,
+                                        lane_done):
+                    metrics[key] = m
+                    if bool(done):
+                        if store is not None:
+                            store.put(fingerprints[key], m)
+                            flushed += 1
+                    else:
+                        info["incomplete"].append(key)
+                steps_total += int(res["steps"])
+                window_peak = max(window_peak, int(res["window"]))
+                budget_cut = budget_cut or not res["finished"]
+                info["chunks"].append({
+                    "structure": tag, "lanes": ch.hi - ch.lo,
+                    "lane_width": ch.lane_width, "devices": ch.n_devices,
+                    "wall_s": ch.wall_s, "steps": int(res["steps"]),
+                    "window": int(res["window"]),
+                    "compile_s": float(res["compile_s"]),
+                    "execute_s": float(res["execute_s"]),
+                    "compile_variants": int(res.get("compile_variants", 0)),
+                    "retraces": int(res["retraces"]),
+                    "aot_rejits": int(res["aot_rejits"]),
+                    "escalations": int(res["escalations"]),
+                    "warm_hits": int(res["warm_hits"]),
+                    "sched_steps": int(np.sum(res["sched_steps"])),
+                    "compressed_events": int(res["compressed_events"]),
+                })
+                info["compile_s"] += float(res["compile_s"])
+                info["execute_s"] += float(res["execute_s"])
+                variants_peak = max(variants_peak,
+                                    int(res.get("compile_variants", 0)))
+                info["retraces"] += int(res["retraces"])
+                info["aot_rejits"] += int(res["aot_rejits"])
+                info["escalations"] += int(res["escalations"])
+                info["warm_hits"] += int(res["warm_hits"])
+                info["sched_steps"] += int(np.sum(res["sched_steps"]))
+                info["compressed_events"] += int(res["compressed_events"])
+                info["peak_lane_width"] = max(info["peak_lane_width"],
+                                              ch.lane_width)
+                info["devices"] = ch.n_devices
+                heartbeat.tick(cells_flushed=flushed)
         info[f"{tag}_lanes"] = len(group)
         info[f"{tag}_steps"] = steps_total
         info[f"{tag}_window"] = window_peak

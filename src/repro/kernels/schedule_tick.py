@@ -298,6 +298,7 @@ def fused_schedule_tick(p, state, alloc, remaining, start_t, act,
                    jax.ShapeDtypeStruct((B, 1, W), jnp.int32),
                    jax.ShapeDtypeStruct((B, 1, W), jnp.float32)],
         interpret=interpret,
+        name="schedule_tick",
     )(*arrs)
     state2, alloc2, start2 = (a[:, 0, :W0] for a in out)
     return (state2.reshape(lane_shape + (W0,)),

@@ -86,6 +86,7 @@ def waterfill(capacity: jax.Array, target, *, block: int = 2048,
         out_shape=jax.ShapeDtypeStruct(cap3.shape, jnp.int32),
         scratch_shapes=[pltpu.VMEM((1, 1), jnp.int32)],
         interpret=interpret,
+        name="waterfill",
     )(jnp.asarray(target, jnp.int32).reshape(1, 1), cap3)
     return out.reshape(-1)[:n]
 

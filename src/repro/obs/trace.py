@@ -21,6 +21,13 @@ Design constraints (the "flight recorder" contract):
   limitation for ``--engine des --workers N``.
 * **Monotonic clocks.**  Timestamps come from ``time.monotonic_ns``
   relative to tracer creation; wall-of-day never appears in a trace.
+* **The profiler's clock too.**  An enabled span also enters a
+  ``jax.profiler.TraceAnnotation`` under its bare name, so a JAX
+  profiler trace shows the program's spans on the host plane beside
+  JAX's own events, on the device trace's clock.  JAX is never imported
+  here: a process that has not loaded it has no profiler to write to,
+  and its spans carry no annotation.  Cross-thread
+  :meth:`Tracer.record_span` intervals have no profiler counterpart.
 * **Chrome trace-event output.**  :meth:`Tracer.chrome_events` returns a
   plain list of complete (``"ph": "X"``) trace events — microsecond
   ``ts``/``dur``, ``pid``/``tid`` — which ``chrome://tracing`` and
@@ -36,6 +43,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from typing import Dict, List, Optional
@@ -57,11 +65,32 @@ class _NullSpan:
 
 _NULL_SPAN = _NullSpan()
 
+# ``os.getpid`` is a system call, and a slow one on some virtualised hosts
+# (about 20 us right after a device read on a TPU v5e host): read it once
+# per process
+_PID = os.getpid()
+
+
+def _refresh_pid() -> None:
+    global _PID
+    _PID = os.getpid()
+
+
+if hasattr(os, "register_at_fork"):
+    os.register_at_fork(after_in_child=_refresh_pid)
+
+
+def _profiler_event(name: str):
+    """A JAX profiler host event called ``name``, or None where the
+    process has not loaded JAX."""
+    jax = sys.modules.get("jax")
+    return None if jax is None else jax.profiler.TraceAnnotation(name)
+
 
 class _Span:
     """A live span; records itself into the tracer on ``__exit__``."""
 
-    __slots__ = ("_tracer", "name", "args", "_t0")
+    __slots__ = ("_tracer", "name", "args", "_t0", "_event")
 
     def __init__(self, tracer: "Tracer", name: str, args: Dict) -> None:
         self._tracer = tracer
@@ -72,6 +101,10 @@ class _Span:
         stack = self._tracer._stack()
         self.args["parent"] = stack[-1] if stack else None
         stack.append(self.name)
+        # the profiler event encloses the span's own interval
+        self._event = _profiler_event(self.name)
+        if self._event is not None:
+            self._event.__enter__()
         self._t0 = time.monotonic_ns()
         return self
 
@@ -81,6 +114,8 @@ class _Span:
         if stack and stack[-1] == self.name:
             stack.pop()
         self._tracer._record(self.name, self._t0, dur_ns, self.args)
+        if self._event is not None:
+            self._event.__exit__(*exc)
         return False
 
 
@@ -115,7 +150,7 @@ class Tracer:
             "ph": "X",
             "ts": (t0_ns - self._epoch_ns) / 1000.0,  # µs, Chrome unit
             "dur": dur_ns / 1000.0,
-            "pid": os.getpid(),
+            "pid": _PID,
             "tid": threading.get_ident(),
             "args": args,
         }

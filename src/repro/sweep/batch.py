@@ -517,7 +517,10 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
     every chunk shares one compilation and the monolithic bit-parity.
     """
     n, B = batch.n_jobs, batch.n_lanes
-    st = lane_statics(batch) if statics is None else statics
+    if statics is None:
+        with obs.span("sweep.statics", lanes=B):
+            statics = lane_statics(batch)
+    st = statics
     # static greedy-priority bounds: every alloc lies in [0, max_nodes]
     prio_lo, prio_hi = st["prio_lo"], st["prio_hi"]
     span_max = st["span_max"]
@@ -604,57 +607,62 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
 
     max_steps = cfg.max_steps_factor * n + 2048
     while steps < max_steps:
-        n_active = int(_peek_active(full["state"]))
-        need = n_active + cfg.reserve_slack
-        if need > W and W < n:
-            escalate(need)
-            if verbose:
-                print(f"[sweep.batch] active={n_active} -> window W={W}")
-        elif W > W0 and need <= W // 2:
-            low_streak += 1
-            if low_streak >= 2:
-                # smallest covering rung that already has an executable:
-                # de-escalation never pays a fresh compile
-                down = [w for w in ladder
-                        if W0 <= w < W and w >= need
-                        and (key_for(w) in _COMPILED_KEYS
-                             or key_for(w) in _WARM_EXECUTABLES)]
-                if down:
-                    W, low_streak = min(down), 0
-        else:
-            low_streak = 0
-        w_peak = max(w_peak, W)
+        # host control flow between chunk calls: the device idles through
+        # each read back (sweep.peek)
+        with obs.span("sweep.peek", window=W, lanes=B):
+            n_active = int(_peek_active(full["state"]))
+            need = n_active + cfg.reserve_slack
+            if need > W and W < n:
+                escalate(need)
+                if verbose:
+                    print(f"[sweep.batch] active={n_active} -> window W={W}")
+            elif W > W0 and need <= W // 2:
+                low_streak += 1
+                if low_streak >= 2:
+                    # smallest covering rung that already has an executable:
+                    # de-escalation never pays a fresh compile
+                    down = [w for w in ladder
+                            if W0 <= w < W and w >= need
+                            and (key_for(w) in _COMPILED_KEYS
+                                 or key_for(w) in _WARM_EXECUTABLES)]
+                    if down:
+                        W, low_streak = min(down), 0
+            else:
+                low_streak = 0
+            w_peak = max(w_peak, W)
 
-        ckey = key_for(W)
-        used_keys.add(ckey)
-        fn, is_warm, first = None, False, False
-        if ckey in _WARM_EXECUTABLES:
-            fn, is_warm = _WARM_EXECUTABLES[ckey], True
-        elif ckey in _WARM_FUTURES:
-            fut = _WARM_FUTURES.pop(ckey)
-            # blocking on an in-flight background compile is compile time;
-            # a failed one raises here — the foreground jit would only
-            # fail the same way, or hide what the device refused
-            first = not fut.done()
-            fn = _WARM_EXECUTABLES[ckey] = fut.result()
-            _COMPILED_KEYS.add(ckey)
-            is_warm = True
-            warm_hits += 1
-            obs.counter("sweep.warm_hits")
-        if fn is None:
-            fn = fn_for(W)
-            if ckey not in _COMPILED_KEYS:
-                _COMPILED_KEYS.add(ckey)
-                first = True
-                retraces += 1
-                obs.counter("sweep.retraces")
-        obs.gauge("sweep.compile_variants", len(_COMPILED_KEYS))
-        k_before = np.asarray(k)
+            ckey = key_for(W)
+            used_keys.add(ckey)
+            fn, fut, is_warm, first = None, None, False, False
+            if ckey in _WARM_EXECUTABLES:
+                fn, is_warm = _WARM_EXECUTABLES[ckey], True
+            elif ckey in _WARM_FUTURES:
+                fut = _WARM_FUTURES.pop(ckey)
+                # blocking on an in-flight background compile is compile
+                # time: the wait runs inside the sweep.compile span below
+                first = not fut.done()
+                is_warm = True
+                warm_hits += 1
+            else:
+                fn = fn_for(W)
+                if ckey not in _COMPILED_KEYS:
+                    _COMPILED_KEYS.add(ckey)
+                    first = True
+                    retraces += 1
+                    obs.counter("sweep.retraces")
+            k_before = np.asarray(k)
         t_call = time.monotonic()
         with obs.span("sweep.compile" if first else "sweep.execute",
                       window=W, lanes=B, scan_steps=cfg.chunk):
+            if fut is not None:
+                # a failed background compile raises here — the
+                # foreground jit would only fail the same way, or hide
+                # what the device refused
+                fn = _WARM_EXECUTABLES[ckey] = fut.result()
+                _COMPILED_KEYS.add(ckey)
             try:
-                out = fn(batch, full, k, retrig, bf, nact, ncomp)
+                with obs.span("sweep.dispatch"):
+                    out = fn(batch, full, k, retrig, bf, nact, ncomp)
             except Exception:
                 if not is_warm:
                     raise
@@ -682,20 +690,23 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
         steps += cfg.chunk
         if done_now:
             break
-        if np.array_equal(k_before, np.asarray(k)):
-            # nothing advanced: every lane is frozen waiting for arrivals
-            # that do not fit -> the window must grow
-            if W >= n:
-                raise SweepEngineError(
-                    "engine stalled with the window at the full job count")
-            escalate(2 * W)
+        with obs.span("sweep.peek", window=W, lanes=B):
+            if np.array_equal(k_before, np.asarray(k)):
+                # nothing advanced: every lane is frozen waiting for arrivals
+                # that do not fit -> the window must grow
+                if W >= n:
+                    raise SweepEngineError(
+                        "engine stalled with the window at the full job count")
+                escalate(2 * W)
 
-    out = {kk: np.asarray(v) for kk, v in full.items()}
-    out["trace_t"] = np.concatenate([t for t, _, _ in traces], axis=1)
-    out["trace_busy"] = np.concatenate([b for _, b, _ in traces], axis=1)
-    out["trace_qlen"] = np.concatenate([q for _, _, q in traces], axis=1)
-    out["bf_starts"] = np.asarray(bf)
-    out["sched_steps"] = np.asarray(nact)
+    # the final state and step timelines read back to the host
+    with obs.span("sweep.collect", lanes=B):
+        out = {kk: np.asarray(v) for kk, v in full.items()}
+        out["trace_t"] = np.concatenate([t for t, _, _ in traces], axis=1)
+        out["trace_busy"] = np.concatenate([b for _, b, _ in traces], axis=1)
+        out["trace_qlen"] = np.concatenate([q for _, _, q in traces], axis=1)
+        out["bf_starts"] = np.asarray(bf)
+        out["sched_steps"] = np.asarray(nact)
     out["steps"] = steps
     out["window"] = w_peak
     out["finished"] = bool(np.all(out["state"] == DONE))
@@ -832,18 +843,19 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
             qlen = jnp.sum((bstate == QUEUED).astype(jnp.int32), axis=-1)
             return st_, (t_now, busy.astype(jnp.int32), qlen)
 
-        halted = jnp.zeros_like(frozen)
-        n_adv = jnp.zeros((B,), jnp.int32)
-        st_ = (bstate, balloc, brem, bstart, bend,
-               k, retrig, frozen, halted, n_adv, nact)
-        st_, emit = micro(st_)
-        emits = [emit]
-        for _ in range(E - 1):
-            live = jnp.any(~st_[8] & ~st_[7])  # ~halted & ~frozen
-            st_, emit = jax.lax.cond(live, micro, dup, st_)
-            emits.append(emit)
-        (bstate, balloc, brem, bstart, bend,
-         k, retrig, frozen, halted, n_adv, nact) = st_
+        with jax.named_scope("chunk.advance"):
+            halted = jnp.zeros_like(frozen)
+            n_adv = jnp.zeros((B,), jnp.int32)
+            st_ = (bstate, balloc, brem, bstart, bend,
+                   k, retrig, frozen, halted, n_adv, nact)
+            st_, emit = micro(st_)
+            emits = [emit]
+            for _ in range(E - 1):
+                live = jnp.any(~st_[8] & ~st_[7])  # ~halted & ~frozen
+                st_, emit = jax.lax.cond(live, micro, dup, st_)
+                emits.append(emit)
+            (bstate, balloc, brem, bstart, bend,
+             k, retrig, frozen, halted, n_adv, nact) = st_
 
         running0 = bstate == RUNNING
         alloc0 = balloc
@@ -867,48 +879,50 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
             with_classes=with_classes, with_sjf=with_sjf,
             pool_share=bj.pool_share, steal_margin=bj.steal_margin)
 
-        # net per-invocation op accounting (jobs running before & after)
-        still = running0 & (bstate == RUNNING)
-        d = balloc - alloc0
-        beops = beops + (still & (d > 0)).astype(jnp.int32)
-        bsops = bsops + (still & (d < 0)).astype(jnp.int32)
+        # per-step accounting: op counts, counters, the timeline fixup
+        with jax.named_scope("chunk.account"):
+            # net per-invocation op accounting (jobs running before & after)
+            still = running0 & (bstate == RUNNING)
+            d = balloc - alloc0
+            beops = beops + (still & (d > 0)).astype(jnp.int32)
+            bsops = bsops + (still & (d < 0)).astype(jnp.int32)
 
-        # scheduling counters (buffer slots are in FCFS submit-rank order,
-        # so "an earlier job is still queued after the pass" is an
-        # exclusive prefix count).  A start with an earlier job left
-        # waiting is exactly an out-of-order (EASY backfill / shrink-
-        # admitted) start — the tick-quantized equivalent of the DES's
-        # post-hoc rule (core.metrics.backfill_starts), so the counters
-        # agree across engines and are execution-plan-invariant.
-        started_now = (state0 == QUEUED) & (bstate == RUNNING)
-        qd = (bstate == QUEUED).astype(jnp.int32)
-        earlier_q = jnp.cumsum(qd, axis=-1) - qd
-        bf = bf + jnp.sum(started_now & (earlier_q > 0),
-                          axis=-1).astype(jnp.int32)
-        ncomp = ncomp + jnp.maximum(n_adv - 1, 0)
+            # scheduling counters (buffer slots are in FCFS submit-rank order,
+            # so "an earlier job is still queued after the pass" is an
+            # exclusive prefix count).  A start with an earlier job left
+            # waiting is exactly an out-of-order (EASY backfill / shrink-
+            # admitted) start — the tick-quantized equivalent of the DES's
+            # post-hoc rule (core.metrics.backfill_starts), so the counters
+            # agree across engines and are execution-plan-invariant.
+            started_now = (state0 == QUEUED) & (bstate == RUNNING)
+            qd = (bstate == QUEUED).astype(jnp.int32)
+            earlier_q = jnp.cumsum(qd, axis=-1) - qd
+            bf = bf + jnp.sum(started_now & (earlier_q > 0),
+                              axis=-1).astype(jnp.int32)
+            ncomp = ncomp + jnp.maximum(n_adv - 1, 0)
 
-        busy = jnp.sum(jnp.where(bstate == RUNNING, balloc, 0), axis=-1)
-        qlen = jnp.sum((bstate == QUEUED).astype(jnp.int32), axis=-1)
-        # rerun next tick while a pass changed state and jobs stayed
-        # queued (no-op passes were cleared in the micro-advance already).
-        # Only lanes whose halting event got a real pass may rewrite the
-        # flag: a lane frozen with a retrig pending (its re-tick would
-        # swallow an unprefetched arrival) must carry it through the
-        # trailing no-op steps and resume the cascade after compaction —
-        # overwriting it here would drop a scheduling invocation and shift
-        # starts by a tick whenever a freeze lands mid-cascade.
-        changed = jnp.any((balloc != alloc0) | (bstate != state0), axis=-1)
-        retrig = jnp.where(halted, changed & (qlen > 0), retrig)
+            busy = jnp.sum(jnp.where(bstate == RUNNING, balloc, 0), axis=-1)
+            qlen = jnp.sum((bstate == QUEUED).astype(jnp.int32), axis=-1)
+            # rerun next tick while a pass changed state and jobs stayed
+            # queued (no-op passes were cleared in the micro-advance already).
+            # Only lanes whose halting event got a real pass may rewrite the
+            # flag: a lane frozen with a retrig pending (its re-tick would
+            # swallow an unprefetched arrival) must carry it through the
+            # trailing no-op steps and resume the cascade after compaction —
+            # overwriting it here would drop a scheduling invocation and shift
+            # starts by a tick whenever a freeze lands mid-cascade.
+            changed = jnp.any((balloc != alloc0) | (bstate != state0), axis=-1)
+            retrig = jnp.where(halted, changed & (qlen > 0), retrig)
 
-        # timeline fixup: the halting event's entry (index n_adv - 1, and
-        # every zero-width duplicate after it) was emitted pre-schedule;
-        # the classic loop emits post-schedule values at that timestamp
-        ts = jnp.stack([e[0] for e in emits])        # (E, B)
-        busy_e = jnp.stack([e[1] for e in emits])
-        qlen_e = jnp.stack([e[2] for e in emits])
-        fix = jnp.arange(E)[:, None] >= jnp.maximum(n_adv - 1, 0)[None, :]
-        busy_e = jnp.where(fix, busy.astype(jnp.int32)[None, :], busy_e)
-        qlen_e = jnp.where(fix, qlen[None, :], qlen_e)
+            # timeline fixup: the halting event's entry (index n_adv - 1, and
+            # every zero-width duplicate after it) was emitted pre-schedule;
+            # the classic loop emits post-schedule values at that timestamp
+            ts = jnp.stack([e[0] for e in emits])        # (E, B)
+            busy_e = jnp.stack([e[1] for e in emits])
+            qlen_e = jnp.stack([e[2] for e in emits])
+            fix = jnp.arange(E)[:, None] >= jnp.maximum(n_adv - 1, 0)[None, :]
+            busy_e = jnp.where(fix, busy.astype(jnp.int32)[None, :], busy_e)
+            qlen_e = jnp.where(fix, qlen[None, :], qlen_e)
 
         carry = (bstate, balloc, brem, bstart, bend, beops, bsops,
                  k, retrig, frozen, bf, nact, ncomp)
@@ -916,66 +930,68 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
 
     @jax.jit
     def run_chunk(batch, full, k, retrig, bf, nact, ncomp):
-        state = full["state"]
-        active = (state == QUEUED) | (state == RUNNING)
-        n_active = jnp.sum(active, axis=-1)
-        pending = state == PENDING
-        ar = jnp.arange(n)[None, :]
-        # first still-pending slot (padding is DONE, so this stays within
-        # the lane's real jobs; n when everything arrived)
-        aptr = jnp.min(jnp.where(pending, ar, n), axis=-1)
+        with jax.named_scope("chunk.compact"):
+            state = full["state"]
+            active = (state == QUEUED) | (state == RUNNING)
+            n_active = jnp.sum(active, axis=-1)
+            pending = state == PENDING
+            ar = jnp.arange(n)[None, :]
+            # first still-pending slot (padding is DONE, so this stays within
+            # the lane's real jobs; n when everything arrived)
+            aptr = jnp.min(jnp.where(pending, ar, n), axis=-1)
 
-        # -- compact active + arrival reserve into W slots (FCFS order) ---
-        reserve = jnp.maximum(W - n_active, 0)
-        sel = active | (pending & (ar < (aptr + reserve)[:, None]))
-        pos = jnp.cumsum(sel, axis=-1) - 1
-        pos = jnp.where(sel & (pos < W), pos, W)  # W: dropped by scatter
-        idx = jnp.full((B, W), n, jnp.int32).at[rows, pos].set(
-            jnp.broadcast_to(ar, (B, n)))
-        slot_ok = idx < n
-        gidx = jnp.minimum(idx, n - 1)
+            # -- compact active + arrival reserve into W slots (FCFS order) ---
+            reserve = jnp.maximum(W - n_active, 0)
+            sel = active | (pending & (ar < (aptr + reserve)[:, None]))
+            pos = jnp.cumsum(sel, axis=-1) - 1
+            pos = jnp.where(sel & (pos < W), pos, W)  # W: dropped by scatter
+            idx = jnp.full((B, W), n, jnp.int32).at[rows, pos].set(
+                jnp.broadcast_to(ar, (B, n)))
+            slot_ok = idx < n
+            gidx = jnp.minimum(idx, n - 1)
 
-        def g2(a, fill):
-            return jnp.where(slot_ok, jnp.take_along_axis(a, gidx, -1), fill)
+            def g2(a, fill):
+                return jnp.where(slot_ok,
+                                 jnp.take_along_axis(a, gidx, -1), fill)
 
-        bj = BatchedLanes(
-            submit=g2(batch.submit, INF),
-            malleable=g2(batch.malleable, False),
-            min_nodes=g2(batch.min_nodes, 1),
-            max_nodes=g2(batch.max_nodes, 1),
-            pfrac=g2(batch.pfrac, jnp.float32(0.0)),
-            inv_ref=g2(batch.inv_ref, jnp.float32(1.0)),
-            wall_work=g2(batch.wall_work, jnp.float32(1.0)),
-            want=g2(batch.want, 1),
-            floor=g2(batch.floor, 1),
-            shrink_floor=g2(batch.shrink_floor, 1),
-            prio_ref=g2(batch.prio_ref, 0),
-            on_demand=g2(batch.on_demand, False),
-            pref_nodes=g2(batch.pref_nodes, 1),
-            sort_key=g2(batch.sort_key, INF),  # padding sorts last
-            capacity=batch.capacity,
-            tick=batch.tick,
-            backfill_depth=batch.backfill_depth,
-            pool_share=batch.pool_share,
-            steal_margin=batch.steal_margin,
-        )
-        n_prefetch = jnp.sum(sel & pending, axis=-1)
-        lim_idx = aptr + n_prefetch
-        arrival_limit = jnp.where(
-            lim_idx < n,
-            jnp.take_along_axis(
-                batch.submit, jnp.minimum(lim_idx, n - 1)[:, None],
-                axis=-1)[:, 0],
-            INF)
+            bj = BatchedLanes(
+                submit=g2(batch.submit, INF),
+                malleable=g2(batch.malleable, False),
+                min_nodes=g2(batch.min_nodes, 1),
+                max_nodes=g2(batch.max_nodes, 1),
+                pfrac=g2(batch.pfrac, jnp.float32(0.0)),
+                inv_ref=g2(batch.inv_ref, jnp.float32(1.0)),
+                wall_work=g2(batch.wall_work, jnp.float32(1.0)),
+                want=g2(batch.want, 1),
+                floor=g2(batch.floor, 1),
+                shrink_floor=g2(batch.shrink_floor, 1),
+                prio_ref=g2(batch.prio_ref, 0),
+                on_demand=g2(batch.on_demand, False),
+                pref_nodes=g2(batch.pref_nodes, 1),
+                sort_key=g2(batch.sort_key, INF),  # padding sorts last
+                capacity=batch.capacity,
+                tick=batch.tick,
+                backfill_depth=batch.backfill_depth,
+                pool_share=batch.pool_share,
+                steal_margin=batch.steal_margin,
+            )
+            n_prefetch = jnp.sum(sel & pending, axis=-1)
+            lim_idx = aptr + n_prefetch
+            arrival_limit = jnp.where(
+                lim_idx < n,
+                jnp.take_along_axis(
+                    batch.submit, jnp.minimum(lim_idx, n - 1)[:, None],
+                    axis=-1)[:, 0],
+                INF)
 
-        carry = (
-            g2(state, jnp.int32(DONE)), g2(full["alloc"], 0),
-            g2(full["remaining"], jnp.float32(0.0)),
-            g2(full["start_t"], jnp.float32(jnp.nan)),
-            g2(full["end_t"], jnp.float32(jnp.nan)),
-            g2(full["expand_ops"], 0), g2(full["shrink_ops"], 0),
-            k, retrig, jnp.zeros((B,), bool), bf, nact, ncomp,
-        )
+            carry = (
+                g2(state, jnp.int32(DONE)), g2(full["alloc"], 0),
+                g2(full["remaining"], jnp.float32(0.0)),
+                g2(full["start_t"], jnp.float32(jnp.nan)),
+                g2(full["end_t"], jnp.float32(jnp.nan)),
+                g2(full["expand_ops"], 0), g2(full["shrink_ops"], 0),
+                k, retrig, jnp.zeros((B,), bool), bf, nact, ncomp,
+            )
         carry, ys = jax.lax.scan(
             lambda c, x: step(bj, batch.capacity, batch.tick,
                               batch.backfill_depth, arrival_limit, c, x),
@@ -986,16 +1002,17 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
         def sc(a, buf):  # idx == n rows are dropped (out of bounds)
             return a.at[rows, idx].set(buf)
 
-        full = dict(
-            state=sc(full["state"], bstate),
-            alloc=sc(full["alloc"], balloc),
-            remaining=sc(full["remaining"], brem),
-            start_t=sc(full["start_t"], bstart),
-            end_t=sc(full["end_t"], bend),
-            expand_ops=sc(full["expand_ops"], beops),
-            shrink_ops=sc(full["shrink_ops"], bsops),
-        )
-        all_done = jnp.all(full["state"] == DONE)
+        with jax.named_scope("chunk.scatter"):
+            full = dict(
+                state=sc(full["state"], bstate),
+                alloc=sc(full["alloc"], balloc),
+                remaining=sc(full["remaining"], brem),
+                start_t=sc(full["start_t"], bstart),
+                end_t=sc(full["end_t"], bend),
+                expand_ops=sc(full["expand_ops"], beops),
+                shrink_ops=sc(full["shrink_ops"], bsops),
+            )
+            all_done = jnp.all(full["state"] == DONE)
         ts, busy, qlen = ys  # (K, E, B): E compressed entries per step
         KE = K * E
 

@@ -126,10 +126,11 @@ class SweepCache:
 
     def put(self, fingerprint: Dict, metrics: Dict[str, float]) -> None:
         obs.counter("store.put")
-        path = self._path(self.key(fingerprint))
-        path.parent.mkdir(parents=True, exist_ok=True)
-        tmp = path.with_suffix(".tmp")
-        tmp.write_text(json.dumps(
-            {"fingerprint": fingerprint, "metrics": metrics}, indent=1,
-            default=float))
-        tmp.replace(path)
+        with obs.span("store.put"):
+            path = self._path(self.key(fingerprint))
+            path.parent.mkdir(parents=True, exist_ok=True)
+            tmp = path.with_suffix(".tmp")
+            tmp.write_text(json.dumps(
+                {"fingerprint": fingerprint, "metrics": metrics}, indent=1,
+                default=float))
+            tmp.replace(path)

@@ -25,6 +25,7 @@ import numpy as np
 
 
 @jax.jit
+@jax.named_scope("metrics.cells")
 def _batched_metrics_device(start, end, expand_ops, shrink_ops, submit,
                             malleable, trace_t, trace_busy, t0, t1, capacity):
     B = start.shape[0]

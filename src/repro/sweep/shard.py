@@ -162,7 +162,8 @@ def simulate_lanes_chunked(
     # compile parameters come from the FULL batch: every chunk shares one
     # compilation, and chunk composition cannot perturb any pass (the
     # balanced level bisection's iteration count follows span_max)
-    statics = lane_statics(batch)
+    with obs.span("sweep.statics", lanes=batch.n_lanes):
+        statics = lane_statics(batch)
     for lo, hi in ranges:
         sub = pad_lanes(take_lanes(batch, lo, hi), width)
         if sharding is not None:

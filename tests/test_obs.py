@@ -165,6 +165,160 @@ def test_heartbeat_disabled_prints_nothing():
 
 
 # ----------------------------------------------------------------------
+# the profiler's clock and the engine loop's spans
+def test_enabled_span_writes_a_profiler_host_event(tmp_path):
+    import sys
+    import time
+
+    import jax
+
+    sys.path.insert(0, str(REPO))
+    from bench.lib import xplane
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        obs.configure(enabled=True)
+        with obs.span("x.y", detail=1):
+            time.sleep(0.002)
+        obs.configure(enabled=False)
+        with obs.span("x.off"):
+            time.sleep(0.002)
+    finally:
+        jax.profiler.stop_trace()
+    profile = xplane.load(xplane.find_xplane(str(tmp_path)))
+    names = [name for name, _, _ in xplane.host_events(profile)]
+    # the bare span name, with no args folded into it
+    assert names.count("x.y") == 1
+    assert not any(n.startswith("x.off") or n.startswith("x.y#")
+                   for n in names)
+
+
+def test_obs_imports_and_traces_without_jax():
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from repro import obs\n"
+        "obs.configure(enabled=True)\n"
+        "with obs.span('a'):\n"
+        "    pass\n"
+        "assert [e['name'] for e in obs.get_tracer().events()] == ['a']\n"
+        "assert 'jax' not in sys.modules\n")
+    env = {"PYTHONPATH": str(REPO / "src"), "PATH": ""}
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_span_carries_the_pid_of_the_process_that_recorded_it():
+    """The pid is read once per process, and again in a forked child."""
+    import subprocess
+    import sys
+
+    code = (
+        "import os\n"
+        "from repro.obs.trace import Tracer\n"
+        "t = Tracer(enabled=True)\n"
+        "r, w = os.pipe()\n"
+        "child = os.fork()\n"
+        "if child == 0:\n"
+        "    with t.span('x.y'):\n"
+        "        pass\n"
+        "    os.write(w, str(t.events()[-1]['pid']).encode())\n"
+        "    os._exit(0)\n"
+        "os.waitpid(child, 0)\n"
+        "assert int(os.read(r, 64)) == child\n"
+        "with t.span('x.y'):\n"
+        "    pass\n"
+        "assert t.events()[-1]['pid'] == os.getpid()\n")
+    env = {"PYTHONPATH": str(REPO / "src"), "PATH": ""}
+    proc = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=60)
+    assert proc.returncode == 0, proc.stderr
+
+
+def test_engine_loop_spans_bracket_each_chunk_call():
+    from repro.core import STRATEGIES, Workload
+    from repro.sweep.batch import EngineConfig, build_lanes, simulate_lanes
+
+    rng = np.random.default_rng(0)
+    w = Workload.rigid(submit=np.sort(rng.uniform(0, 150, 20)),
+                       runtime=rng.uniform(20, 120, 20),
+                       nodes_req=rng.choice([1, 2, 4, 8], 20))
+    batch, _ = build_lanes(w, 10, [(STRATEGIES["easy"], 0.0, 0),
+                                   (STRATEGIES["min"], 0.6, 0)])
+    obs.configure(enabled=True)
+    simulate_lanes(batch, EngineConfig(window=4, chunk=16))
+    evs = obs.get_tracer().events()
+
+    def intervals(*names):
+        return [(e["ts"], e["ts"] + e["dur"]) for e in evs
+                if e["name"] in names]
+
+    calls = intervals("sweep.compile", "sweep.execute")
+    peeks = intervals("sweep.peek")
+    dispatches = intervals("sweep.dispatch")
+    assert len(calls) >= 2
+    assert len(dispatches) == len(calls)
+    assert len(peeks) >= len(calls)
+    for lo, hi in dispatches:
+        assert any(a <= lo and hi <= b for a, b in calls)
+    for lo, hi in peeks:
+        assert all(hi <= a or b <= lo for a, b in calls)
+    assert all(e["args"]["window"] >= 4 and e["args"]["lanes"] == 2
+               for e in evs if e["name"] == "sweep.peek")
+
+
+def test_wait_on_a_background_compile_is_compile_time(monkeypatch):
+    """A chunk call that blocks on its rung's in-flight AOT compile counts
+    the wait inside its ``sweep.compile`` span and in ``compile_s``."""
+    import concurrent.futures
+    import time
+
+    from repro.core import STRATEGIES, Workload
+    from repro.sweep import batch as batch_mod
+
+    monkeypatch.setattr(batch_mod, "_COMPILED_KEYS", set())
+    monkeypatch.setattr(batch_mod, "_WARM_EXECUTABLES", {})
+    monkeypatch.setattr(batch_mod, "_WARM_FUTURES", {})
+
+    class InFlight(concurrent.futures.Future):
+        """A background compile still running when the engine asks."""
+
+        def __init__(self, compile_fn):
+            super().__init__()
+            self.compile_fn = compile_fn
+
+        def done(self):
+            return False
+
+        def result(self, timeout=None):
+            time.sleep(0.3)
+            return self.compile_fn()
+
+    class Pool:
+        def submit(self, fn):
+            return InFlight(fn)
+
+    monkeypatch.setattr(batch_mod, "_warm_pool", Pool)
+    rng = np.random.default_rng(0)
+    w = Workload.rigid(submit=np.sort(rng.uniform(0, 150, 20)),
+                       runtime=rng.uniform(20, 120, 20),
+                       nodes_req=rng.choice([1, 2, 4, 8], 20))
+    batch, _ = batch_mod.build_lanes(w, 10, [(STRATEGIES["easy"], 0.0, 0)])
+    obs.configure(enabled=True)
+    # a pinned floor of 4 escalates at once to a rung warmed off-thread
+    res = batch_mod.simulate_lanes(batch, batch_mod.EngineConfig(
+        window=4, chunk=16))
+    assert res["warm_hits"] >= 1
+    waited = [e for e in obs.get_tracer().events()
+              if e["name"] == "sweep.compile" and e["args"]["window"] > 4]
+    assert waited and all(e["dur"] >= 0.3e6 for e in waited)
+    assert res["compile_s"] >= 0.3 * len(waited)
+
+
+# ----------------------------------------------------------------------
 # observability never leaks into identity or results
 def test_fingerprints_identical_with_tracing_on_and_off():
     spec = ExperimentSpec(**TINY)

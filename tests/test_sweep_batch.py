@@ -248,3 +248,25 @@ def test_cache_key_sensitive_to_cell_identity(tmp_path):
         other = dict(base)
         other[field] = value
         assert SweepCache.key(cell_fingerprint(**other)) != k0, field
+
+
+@pytest.mark.parametrize("structure", ["greedy", "pooled"])
+def test_chunk_program_carries_named_scopes(structure):
+    """The compiled chunk program names its phases and the scheduling
+    pass's steps in op metadata, where a device trace can find them."""
+    import re
+
+    from repro.sweep.batch import _chunk_fn, chunk_arg_shapes
+
+    n, B, W = 64, 2, 16
+    fn = _chunk_fn(EngineConfig(structure=structure, chunk=4), n, B, W,
+                   -64, 64, 64)
+    text = fn.lower(*chunk_arg_shapes(n, B)).compile().as_text()
+    scopes = {part for op in re.findall(r'op_name="([^"]*)"', text)
+              for part in op.split("/")}
+    want = {"chunk.compact", "chunk.advance", "chunk.account",
+            "chunk.scatter", "sched.fcfs", "sched.backfill", "sched.shrink",
+            "sched.expand"}
+    if structure == "pooled":  # greedy has no Step 2b
+        want.add("sched.extra")
+    assert want <= scopes

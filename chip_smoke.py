@@ -14,10 +14,15 @@ trace is generated from the seed.  Every phase runs in this one process
 3. serve: what-if queries, two of them identical and concurrent, through
    ``WhatIfEngine(engine="jax")`` on its own fresh store.
 
-Checks: every cell computed fresh (no store hit, no lane cut by the step
-budget); greedy cells bit-identical between ``bisect`` and ``fused``, with
-the kernel (``tpu_custom_call``) in the fused chunk programs; DES
-agreement; served answers equal to the sweep's.  A failed check, or no
+Before them, the scheduling pass's prefix sum (``passes.prefix_sum``) runs
+on ``(16, W)`` int32 (the full range) and bool arrays at the engine's
+window widths and the whole log.
+
+Checks: the prefix sum equals ``numpy.cumsum`` and its program holds the
+blocked form's matmul; every cell computed fresh (no store hit, no lane
+cut by the step budget); greedy cells bit-identical between ``bisect``
+and ``fused``, with the kernel (``tpu_custom_call``) in the fused chunk
+programs; DES agreement; served answers equal to the sweep's.  A failed check, or no
 TPU, exits non-zero.  The last line of stdout is the JSON result.
 
   python3 chip_smoke.py                # one chip
@@ -44,6 +49,7 @@ STRATEGIES = ("keeppref", "min", "avg", "pref_common_pool",
               "steal_agreement")
 PROPORTIONS = (0.5, 1.0)
 DES_CELLS = (("easy", 0.0, 0), ("keeppref", 0.5, 0))
+PREFIX_WIDTHS = (4096, 8192, 16384, 28259)  # window rungs and the whole log
 
 
 def smoke_spec(scale: float = SCALE, strategies=STRATEGIES,
@@ -111,6 +117,29 @@ def engine_stats() -> dict:
             "escalations": int(counts.get("sweep.escalations", 0)),
             "aot_rejits": int(counts.get("sweep.aot_rejits", 0)),
             "window_peak": max(windows, default=0)}
+
+
+def prefix_phase(checks: Checks, widths=PREFIX_WIDTHS, rows: int = 16):
+    """``passes.prefix_sum`` on this platform against ``numpy.cumsum``;
+    the blocked form is taken on TPU only."""
+    import jax
+    import numpy as np
+
+    from repro.core.passes import prefix_sum
+
+    rng = np.random.default_rng(0)
+    fn = jax.jit(prefix_sum)
+    on_tpu = jax.devices()[0].platform == "tpu"
+    for w in widths:
+        ints = rng.integers(-2**31, 2**31, (rows, w)).astype(np.int32)
+        for x in (ints, rng.random((rows, w)) < 0.5):
+            ref = np.cumsum(x, axis=-1, dtype=np.int64).astype(np.int32)
+            blocked = "dot_general" in fn.lower(x).as_text()
+            checks(f"prefix_sum {x.dtype} ({rows}, {w}): equals "
+                   "numpy.cumsum", np.array_equal(np.asarray(fn(x)), ref))
+            checks(f"prefix_sum {x.dtype} ({rows}, {w}): "
+                   + ("blocked form" if on_tpu else "jnp.cumsum"),
+                   blocked == on_tpu)
 
 
 def sweep_phase(spec, store_dir: pathlib.Path, checks: Checks, *,
@@ -232,6 +261,7 @@ def one_chip(spec, out: pathlib.Path, dev, checks: Checks,
     from repro.core import get_strategy
     from repro.sweep import batch as sb
 
+    run_phase("prefix", lambda: prefix_phase(checks), report, dev)
     bisect, _ = run_phase(
         "sweep_bisect", lambda: sweep_phase(spec, out / "store-bisect",
                                             checks, label="sweep bisect"),

@@ -308,6 +308,70 @@ def _speedup_f32(n, p):
     return 1.0 / ((1.0 - p) + p / n)
 
 
+PREFIX_BLOCK = 128  # slots per MXU block of blocked_prefix_sum
+
+
+def prefix_sum(x):
+    """Inclusive sum along the last axis, bitwise equal to ``jnp.cumsum``.
+
+    On TPU a window-wide ``jnp.cumsum`` lowers to one ``reduce_window``
+    over the whole axis, which runs there several times slower than the
+    exact matmuls of :func:`blocked_prefix_sum`.  Where the program is
+    lowered for TPU, bool and int32 axes take that form; every other
+    platform and dtype keeps ``jnp.cumsum`` (on the CPU the matmuls are
+    the slower form).
+    """
+    import jax
+    jnp = _jnp()
+    if x.dtype not in (jnp.bool_, jnp.int32):
+        return jnp.cumsum(x, axis=-1)
+    with jax.named_scope("pass.prefix"):
+        return _platform_prefix_sum(x)
+
+
+def _platform_prefix_sum(x):
+    import jax
+    return jax.lax.platform_dependent(
+        x, tpu=blocked_prefix_sum,
+        default=lambda x: _jnp().cumsum(x, axis=-1))
+
+
+def blocked_prefix_sum(x):
+    """``jnp.cumsum(x, axis=-1)`` of a bool or int32 array, on the MXU.
+
+    The axis is padded to whole ``PREFIX_BLOCK``-slot blocks.  A block's
+    inclusive prefix is a matmul with an upper-triangular ones matrix, run
+    on the 8-bit digits of the two's-complement value (one digit for
+    bool).  Two digits share a matmul as ``lo + 256 * hi``: every operand
+    is exact in bf16, and a block's sum (at most 128 * 65535 < 2^24) is
+    exact in the f32 accumulator.  The parts recombine by int32 shifts and
+    adds, which wrap mod 2^32 as ``jnp.cumsum`` does, and the exclusive
+    prefix of the block totals, the same sum one level up, carries the
+    blocks.
+    """
+    jnp = _jnp()
+    *lead, w = x.shape
+    nb = -(-w // PREFIX_BLOCK)
+    nbytes = 1 if x.dtype == jnp.bool_ else 4
+    xb = jnp.pad(x.astype(jnp.int32),
+                 [(0, 0)] * len(lead) + [(0, nb * PREFIX_BLOCK - w)]
+                 ).reshape(*lead, nb, PREFIX_BLOCK)
+    tri = jnp.triu(jnp.ones((PREFIX_BLOCK, PREFIX_BLOCK), jnp.bfloat16))
+    out = 0
+    for lo in range(0, nbytes, 2):
+        ks = range(lo, min(lo + 2, nbytes))
+        d = jnp.concatenate(
+            [((xb >> (8 * k)) & 0xFF).astype(jnp.bfloat16) for k in ks],
+            axis=-1)
+        t = jnp.concatenate([tri * 256 ** (k - lo) for k in ks], axis=0)
+        part = jnp.matmul(d, t, preferred_element_type=jnp.float32)
+        out = out + (part.astype(jnp.int32) << (8 * lo))
+    if nb > 1:
+        tot = jnp.sum(xb, axis=-1)
+        out = out + (blocked_prefix_sum(tot) - tot)[..., None]
+    return out.reshape(*lead, nb * PREFIX_BLOCK)[..., :w]
+
+
 def first_true(mask):
     """Mask of the first True slot per lane (all-False lanes stay empty)."""
     jnp = _jnp()
@@ -337,12 +401,11 @@ def queue_ranks(queued, on_demand=None):
     """
     jnp = _jnp()
     if on_demand is None:
-        return jnp.cumsum(queued, axis=-1)
+        return prefix_sum(queued)
     q_od = queued & on_demand
     n_od = jnp.sum(q_od, axis=-1)
-    return jnp.where(on_demand, jnp.cumsum(q_od, axis=-1),
-                     n_od[..., None] + jnp.cumsum(queued & ~on_demand,
-                                                  axis=-1))
+    r_od, r_n = prefix_sum(jnp.stack([q_od, queued & ~on_demand]))
+    return jnp.where(on_demand, r_od, n_od[..., None] + r_n)
 
 
 def queue_cumsum(amount, mask, on_demand=None):
@@ -355,12 +418,12 @@ def queue_cumsum(amount, mask, on_demand=None):
     """
     jnp = _jnp()
     if on_demand is None:
-        return jnp.cumsum(jnp.where(mask, amount, 0), axis=-1)
+        return prefix_sum(jnp.where(mask, amount, 0))
     a_od = jnp.where(mask & on_demand, amount, 0)
     a_n = jnp.where(mask & ~on_demand, amount, 0)
-    return jnp.where(
-        on_demand, jnp.cumsum(a_od, axis=-1),
-        jnp.sum(a_od, axis=-1, keepdims=True) + jnp.cumsum(a_n, axis=-1))
+    c_od, c_n = prefix_sum(jnp.stack([a_od, a_n]))
+    return jnp.where(on_demand, c_od,
+                     jnp.sum(a_od, axis=-1, keepdims=True) + c_n)
 
 
 def take_desc_prefix(prio, amount, need, lo0: int, hi0: int):
@@ -386,7 +449,7 @@ def take_desc_prefix(prio, amount, need, lo0: int, hi0: int):
     theta = hi  # smallest threshold whose above-take fits within need
     rem = need - s_hi
     tie = prio == theta[..., None]
-    before = jnp.cumsum(jnp.where(tie, amount, 0), axis=-1)
+    before = prefix_sum(jnp.where(tie, amount, 0))
     tie_take = jnp.clip(rem[..., None] - (before - amount), 0, amount)
     return jnp.where(prio > theta[..., None], amount,
                      jnp.where(tie, tie_take, 0))
@@ -564,19 +627,19 @@ def schedule_tick(p: PassParams, state, alloc, remaining, start_t, act,
             # submit order); non-on-demand slots may only join the prefix when
             # every queued on-demand job started.
             q_od = queued & od
-            cumw_od = jnp.cumsum(jnp.where(q_od, p.want, 0), axis=-1)
+            q_n = queued & ~od
+            cumw_od, cumw_n = prefix_sum(jnp.stack(
+                [jnp.where(q_od, p.want, 0), jnp.where(q_n, p.want, 0)]))
             s1o = q_od & (cumw_od <= free[..., None])
             used_od = jnp.max(jnp.where(s1o, cumw_od, 0), axis=-1)
             all_od = ~jnp.any(q_od & ~s1o, axis=-1)
             rem = free - used_od
-            q_n = queued & ~od
-            cumw_n = jnp.cumsum(jnp.where(q_n, p.want, 0), axis=-1)
             s1 = s1o | (q_n & (cumw_n <= rem[..., None]) & all_od[..., None])
             leftover = rem - jnp.max(
                 jnp.where(s1 & ~od, cumw_n, 0), axis=-1)
             h_mask = priority_head(queued & ~s1, od)
         else:
-            cumw = jnp.cumsum(jnp.where(queued, p.want, 0), axis=-1)
+            cumw = prefix_sum(jnp.where(queued, p.want, 0))
             s1 = queued & (cumw <= free[..., None])
             used = jnp.max(jnp.where(s1, cumw, 0), axis=-1)
             leftover = free - used

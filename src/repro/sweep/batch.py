@@ -87,7 +87,8 @@ import numpy as np
 
 from repro import obs
 from repro.core.jobs import DONE, PENDING, QUEUED, RUNNING, Workload
-from repro.core.passes import PassParams, schedule_tick, start_policies
+from repro.core.passes import (PassParams, prefix_sum, schedule_tick,
+                               start_policies)
 from repro.core.scenario import DEFAULT_BACKFILL_DEPTH
 from repro.core.speedup import (TransformConfig, amdahl_speedup,
                                 batched_malleable_params)
@@ -895,8 +896,8 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
             # post-hoc rule (core.metrics.backfill_starts), so the counters
             # agree across engines and are execution-plan-invariant.
             started_now = (state0 == QUEUED) & (bstate == RUNNING)
-            qd = (bstate == QUEUED).astype(jnp.int32)
-            earlier_q = jnp.cumsum(qd, axis=-1) - qd
+            q_after = bstate == QUEUED
+            earlier_q = prefix_sum(q_after) - q_after
             bf = bf + jnp.sum(started_now & (earlier_q > 0),
                               axis=-1).astype(jnp.int32)
             ncomp = ncomp + jnp.maximum(n_adv - 1, 0)
@@ -943,7 +944,7 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
             # -- compact active + arrival reserve into W slots (FCFS order) ---
             reserve = jnp.maximum(W - n_active, 0)
             sel = active | (pending & (ar < (aptr + reserve)[:, None]))
-            pos = jnp.cumsum(sel, axis=-1) - 1
+            pos = prefix_sum(sel) - 1
             pos = jnp.where(sel & (pos < W), pos, W)  # W: dropped by scatter
             idx = jnp.full((B, W), n, jnp.int32).at[rows, pos].set(
                 jnp.broadcast_to(ar, (B, n)))

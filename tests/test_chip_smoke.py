@@ -3,7 +3,8 @@
 Without a TPU the script must fail and print no result — from the repo,
 and from a directory that holds nothing of the repo but the script.  Its
 phase functions, steered here to a tiny scale with interpret kernels and
-fresh stores, must compute every cell and agree with ``run_experiment``.
+fresh stores, must compute every cell and agree with ``run_experiment``,
+and the prefix sum must equal ``numpy.cumsum`` through ``jnp.cumsum``.
 """
 import importlib.util
 import os
@@ -44,6 +45,7 @@ def test_phases_compute_every_cell_and_agree(smoke, tmp_path):
     from repro.sweep.cache import SweepCache
 
     checks = smoke.Checks()
+    smoke.prefix_phase(checks, widths=(1024, 4099), rows=3)
     spec = smoke.smoke_spec(scale=0.005)
     bisect, _ = smoke.sweep_phase(spec, tmp_path / "bisect", checks)
     fused, _ = smoke.sweep_phase(spec, tmp_path / "fused", checks,

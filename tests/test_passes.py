@@ -548,3 +548,65 @@ def test_fcfs_lane_inside_sjf_batch_is_bit_identical():
                                   res_solo["end_t"][0])
     # and the SJF lane actually differs somewhere (the axis is live)
     assert np.any(res_mixed["start_t"][1] != res_solo["start_t"][0])
+
+
+# ------------------------------------------------- blocked prefix sums
+@pytest.mark.parametrize("w", [1, 127, 128, 129, 4096, 16384, 28259])
+@pytest.mark.parametrize("lanes", [(), (16,)], ids=["row", "lanes"])
+@pytest.mark.parametrize("dtype", ["int32", "bool"])
+def test_blocked_prefix_sum_equals_cumsum(w, lanes, dtype):
+    """The MXU form is bitwise ``jnp.cumsum``: int32 over the full range,
+    wraparound included, and bool, whatever the padding to blocks."""
+    import jax
+    rng = np.random.default_rng(w)
+    shape = lanes + (w,)
+    if dtype == "bool":
+        x = rng.random(shape) < 0.5
+    else:
+        x = rng.integers(-2**31, 2**31, shape).astype(np.int32)
+        x[..., ::3] = np.int32(2**31 - 1)  # long runs that wrap
+    got = jax.jit(passes.blocked_prefix_sum)(x)
+    want = jnp.cumsum(jnp.asarray(x), axis=-1)
+    assert got.dtype == want.dtype
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("structure,lanes", [
+    ("greedy", [("easy", 0.0), ("min", 0.6), ("keeppref", 1.0),
+                ("rigid_sjf", 0.0)]),
+    ("balanced", [("avg", 0.5), ("avg", 1.0)]),
+], ids=["greedy_sjf", "balanced"])
+def test_engine_bitwise_with_blocked_prefix_sums(structure, lanes,
+                                                 monkeypatch):
+    """A batch whose every prefix sum takes the blocked form, as where the
+    chunk program is lowered for TPU, returns the same bits as the
+    ``jnp.cumsum`` program.  Some jobs are on-demand (class lanes), and
+    the queue outgrows one 128-slot block."""
+    import functools
+
+    from repro.core.jobs import CLASS_ON_DEMAND
+    from repro.sweep import batch as sb
+
+    w = _wl(seed=5, n=200, hi=300.0)
+    w.job_class[::4] = CLASS_ON_DEMAND
+    batch, _ = build_lanes(w, TINY.nodes,
+                           [(STRATEGIES[s], p, 0) for s, p in lanes])
+    cfg = EngineConfig(structure=structure, window=16, chunk=32,
+                       aot_warmup=False)
+    ref = simulate_lanes(batch, cfg)
+
+    monkeypatch.setattr(passes, "_platform_prefix_sum",
+                        passes.blocked_prefix_sum)
+    # trace the chunk programs afresh under the patch
+    monkeypatch.setattr(sb, "_chunk_fn",
+                        functools.cache(sb._chunk_fn.__wrapped__))
+    for name in ("_COMPILED_KEYS", "_WARM_EXECUTABLES", "_WARM_FUTURES"):
+        monkeypatch.setattr(sb, name, type(getattr(sb, name))())
+    got = simulate_lanes(batch, cfg)
+
+    assert ref["finished"] and got["finished"]
+    assert ref["compile_variants"] > 0 and got["compile_variants"] > 0
+    for key, val in ref.items():
+        if isinstance(val, np.ndarray):
+            assert got[key].dtype == val.dtype, key
+            np.testing.assert_array_equal(got[key], val, err_msg=key)

@@ -4,12 +4,15 @@ Interpret-mode parity (``tests/test_passes.py``, ``tests/test_kernels.py``)
 shows that the kernels compute the reference values; it cannot show that
 Mosaic accepts them.  These tests compile for a *described* v5e chip (no
 chip attached): the fused ``schedule_tick`` kernel at window-ladder widths,
-the waterfill kernel, and one engine chunk program with the fused backend.
+the waterfill kernel, one engine chunk program with the fused backend, and
+one with the reference pass, whose prefix sums must take the MXU form.
 
 The topology is described inside a module fixture — never at import — so
 every xdist worker collects the same tests and only the worker that runs
 this file loads the TPU compiler.
 """
+import re
+
 import pytest
 
 W_LADDER = (128, 512, 2048)
@@ -96,3 +99,20 @@ def test_engine_chunk_compiles_with_fused_kernel(one_chip):
                    depth_bounded=True)
     compiled = fn.lower(*chunk_arg_shapes(n, B, one_chip)).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_engine_chunk_prefix_sums_run_on_the_mxu(one_chip):
+    """The reference pass's chunk program, lowered for TPU, takes the
+    blocked prefix sums: matmuls, and no window-wide ``reduce-window``."""
+    from repro.sweep.batch import EngineConfig, _chunk_fn, chunk_arg_shapes
+
+    n, B, W = 2048, 8, 512
+    fn = _chunk_fn(EngineConfig(), n, B, W, -1024, 1792, 1792, False,
+                   with_sjf=False, depth_bounded=True)
+    text = fn.lower(*chunk_arg_shapes(n, B, one_chip)).compile().as_text()
+    convs = [ln for ln in text.splitlines() if " convolution(" in ln]
+    assert convs
+    assert "reduce-window" not in text
+    # every matmul is the helper's, named by its scope
+    for ln in convs:
+        assert "/pass.prefix/" in re.search(r'op_name="([^"]*)"', ln)[1]

@@ -691,38 +691,35 @@ def schedule_tick(p: PassParams, state, alloc, remaining, start_t, act,
             extra = jnp.where(blocked, ex_b,
                               jnp.where(has_head, free - hfloor, free))
 
-            def qcumsum(amount, mask):
-                return queue_cumsum(amount, mask, od)
-
             tfit = t_now[..., None] + p.wall_work / _speedup_f32(
                 p.want, p.pfrac) <= shadow[..., None] + _SHADOW_EPS
             for _ in range(fill_rounds):
                 cand = (state == QUEUED) & act & ~h_mask & depth_ok
-                # (a) finishes before the reservation: free nodes only
-                c = cand & tfit & (p.want <= free[..., None])
-                cum = qcumsum(p.want, c)
-                s = c & (cum <= free[..., None])
+                # each candidate's start as the DES's first-fit scan picks
+                # it: at want when it finishes before the reservation or
+                # its want fits the spare-node pool, else at floor from the
+                # pool; a job that runs past the reservation draws on the
+                # pool as well as on the free nodes
+                at_want = cand & (p.want <= free[..., None]) & (
+                    tfit | (p.want <= extra[..., None]))
+                at_floor = cand & ~at_want & ~tfit & (
+                    p.floor <= jnp.minimum(free, extra)[..., None])
+                take = jnp.where(at_want, p.want,
+                                 jnp.where(at_floor, p.floor, 0))
+                # one pass in queue order: both sums only grow along the
+                # queue, so the fitting candidates are a prefix of them and
+                # the first that does not fit ends the round (the next
+                # round scans on past it, as the DES skips it)
+                cum, cum_x = queue_cumsum(
+                    jnp.stack([take, jnp.where(tfit, 0, take)]),
+                    at_want | at_floor, od)
+                s = (at_want | at_floor) & (cum <= free[..., None]) & (
+                    cum_x <= extra[..., None])
                 free = free - jnp.max(jnp.where(s, cum, 0), axis=-1)
-                # (b) runs past the reservation: spare-node pool, at want
-                lim = jnp.minimum(free, extra)
-                c2 = cand & ~s & ~tfit & (p.want <= lim[..., None])
-                cum2 = qcumsum(p.want, c2)
-                s2 = c2 & (cum2 <= lim[..., None])
-                take2 = jnp.max(jnp.where(s2, cum2, 0), axis=-1)
-                # (c) spare-node pool at floor (want did not fit)
-                lim3 = jnp.minimum(free - take2, extra - take2)
-                c3 = cand & ~s & ~s2 & ~tfit & (p.floor <= lim3[..., None])
-                cum3 = qcumsum(p.floor, c3)
-                s3 = c3 & (cum3 <= lim3[..., None])
-                take3 = jnp.max(jnp.where(s3, cum3, 0), axis=-1)
-
-                free = free - take2 - take3
-                extra = extra - take2 - take3
-                new = s | s2 | s3
-                alloc = jnp.where(s | s2, p.want,
-                                  jnp.where(s3, p.floor, alloc))
-                state = jnp.where(new, RUNNING, state)
-                start_t = jnp.where(new, t_now[..., None], start_t)
+                extra = extra - jnp.max(jnp.where(s, cum_x, 0), axis=-1)
+                alloc = jnp.where(s, take, alloc)
+                state = jnp.where(s, RUNNING, state)
+                start_t = jnp.where(s, t_now[..., None], start_t)
             return state, alloc, start_t, free
 
         state, alloc, start_t, free = jax.lax.cond(

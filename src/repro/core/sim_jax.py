@@ -15,8 +15,8 @@ and property-tested:
     exact event times);
   * EASY backfill uses the shared vectorized shadow-time reservation
     (:func:`repro.core.passes.shadow_reservation`): candidates start in
-    cumulative-fit rounds rather than the DES's sequential first-fit scan,
-    but the reserved queue head is never delayed — same as the DES;
+    rounds of the DES's first-fit scan, each up to the first candidate it
+    skips, and the reserved queue head is never delayed — same as the DES;
   * Step 2 shrink is applied once per tick rather than to fixpoint — the
     schedule converges over subsequent ticks (the JAX engine runs *every*
     tick, so the paper's tick semantics still hold).

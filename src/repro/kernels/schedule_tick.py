@@ -176,27 +176,20 @@ def _tick_kernel(state_ref, alloc_ref, remaining_ref, start_ref, act_ref,
     tfit = t_now + wall / _speedup_f32(want, pfrac) <= shadow + _SHADOW_EPS
     for _ in range(fill_rounds):
         cand = (state == QUEUED) & behind_head
-        c = cand & tfit & (want <= free)
-        cum = lane_cumsum(jnp.where(c, want, 0))
-        s = c & (cum <= free)
+        at_want = cand & (want <= free) & (tfit | (want <= extra))
+        at_floor = cand & ~at_want & ~tfit & (floor <= jnp.minimum(free,
+                                                                   extra))
+        take = jnp.where(at_want, want, jnp.where(at_floor, floor, 0))
+        fits = at_want | at_floor
+        cum = lane_cumsum(take)
+        cum_x = lane_cumsum(jnp.where(tfit, 0, take))
+        s = fits & (cum <= free) & (cum_x <= extra)
         free = free - jnp.max(jnp.where(s, cum, 0), axis=-1, keepdims=True)
-        lim = jnp.minimum(free, extra)
-        c2 = cand & ~s & ~tfit & (want <= lim)
-        cum2 = lane_cumsum(jnp.where(c2, want, 0))
-        s2 = c2 & (cum2 <= lim)
-        take2 = jnp.max(jnp.where(s2, cum2, 0), axis=-1, keepdims=True)
-        lim3 = jnp.minimum(free - take2, extra - take2)
-        c3 = cand & ~s & ~s2 & ~tfit & (floor <= lim3)
-        cum3 = lane_cumsum(jnp.where(c3, floor, 0))
-        s3 = c3 & (cum3 <= lim3)
-        take3 = jnp.max(jnp.where(s3, cum3, 0), axis=-1, keepdims=True)
-
-        free = free - take2 - take3
-        extra = extra - take2 - take3
-        new = s | s2 | s3
-        alloc = jnp.where(s | s2, want, jnp.where(s3, floor, alloc))
-        state = jnp.where(new, RUNNING, state)
-        start_t = jnp.where(new, t_now, start_t)
+        extra = extra - jnp.max(jnp.where(s, cum_x, 0), axis=-1,
+                                keepdims=True)
+        alloc = jnp.where(s, take, alloc)
+        state = jnp.where(s, RUNNING, state)
+        start_t = jnp.where(s, t_now, start_t)
 
     # -- Step 2: greedy shrink to admit the head --------------------------
     deficit = jnp.where(has_head, hfloor - free, 0)

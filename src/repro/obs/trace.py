@@ -62,6 +62,9 @@ class _NullSpan:
     def __exit__(self, *exc) -> bool:
         return False
 
+    def set(self, **args) -> None:
+        pass
+
 
 _NULL_SPAN = _NullSpan()
 
@@ -117,6 +120,10 @@ class _Span:
         if self._event is not None:
             self._event.__exit__(*exc)
         return False
+
+    def set(self, **args) -> None:
+        """Add args known only inside the span."""
+        self.args.update(args)
 
 
 class Tracer:

@@ -67,8 +67,9 @@ execution layer lives in :mod:`repro.sweep.shard`.
 Fidelity vs. the reference DES (documented in ``sweep/README.md``):
 completions and starts quantized to tick boundaries; EASY backfill honours
 the head's shadow-time reservation (:func:`repro.core.passes.
-shadow_reservation`) but fills candidates in cumulative rounds rather than
-the DES's sequential first-fit scan; shrink/expand tie-break in FCFS order
+shadow_reservation`) and scans candidates in queue order as the DES does,
+but a pass stops after ``fill_rounds`` skipped candidates and the scan goes
+on at the next tick; shrink/expand tie-break in FCFS order
 rather than the DES running-set insertion order; scheduling converges over
 subsequent ticks instead of an in-tick fixpoint.  ``runner.py
 --crosscheck`` quantifies the resulting metric deltas against the DES per
@@ -104,10 +105,16 @@ from repro.core.strategies import Strategy, effective_queue_order
 # structures (pref_common_pool, steal_agreement), per-lane pool-share /
 # steal-margin / preferred-allocation data, and the queue-order axis
 # (per-lane SJF sort keys permuting the slot-window queue order).
-ENGINE_VERSION = 4
+# v5: each EASY fill round is one first-fit pass in queue order, as the
+# DES scans, in place of three passes (finishes-before-the-reservation
+# candidates first, then the spare-pool ones at want, then at floor);
+# a job completes at a tick only if at most _REM_EPS of a tick of its
+# work is left, as the DES ends it there (the threshold was a fraction
+# of the job, which let a job of more than 1e5 ticks end a tick early).
+ENGINE_VERSION = 5
 
 _TICK_EPS = 1e-6   # ceil guard, matches the DES event quantization
-_REM_EPS = 1e-5    # remaining-work completion threshold (fraction of job)
+_REM_EPS = 1e-3    # completion threshold: remaining run time, in ticks
 
 
 class SweepEngineError(RuntimeError):
@@ -610,8 +617,9 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
     while steps < max_steps:
         # host control flow between chunk calls: the device idles through
         # each read back (sweep.peek)
-        with obs.span("sweep.peek", window=W, lanes=B):
+        with obs.span("sweep.peek", window=W, lanes=B) as peek:
             n_active = int(_peek_active(full["state"]))
+            peek.set(active=n_active)
             need = n_active + cfg.reserve_slack
             if need > W and W < n:
                 escalate(need)
@@ -799,7 +807,8 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
             # progress + tick-quantized completions (dt = 0 lanes advance
             # by exactly 0.0: bit-exact identity on brem)
             brem = jnp.where(running, brem - dt[:, None] * rate, brem)
-            done_now = running & (brem <= _REM_EPS) & act[:, None]
+            done_now = running & (brem <= _REM_EPS * tick[:, None] * rate) \
+                & act[:, None]
             bstate = jnp.where(done_now, DONE, bstate)
             bend = jnp.where(done_now, t_next[:, None], bend)
             balloc = jnp.where(done_now, 0, balloc)

@@ -65,6 +65,16 @@ def test_span_nesting_records_parents():
     assert by_name["outer"]["dur"] >= by_name["inner"]["dur"]
 
 
+def test_span_set_adds_args_known_inside_it():
+    with obs.span("off") as sp:
+        sp.set(found=1)  # disabled: a no-op on the shared span
+    obs.configure(enabled=True)
+    with obs.span("on", given=1) as sp:
+        sp.set(found=2)
+    (ev,) = obs.get_tracer().events()
+    assert ev["args"]["given"] == 1 and ev["args"]["found"] == 2
+
+
 def test_span_thread_safety():
     obs.configure(enabled=True)
     n_threads, n_spans = 8, 50
@@ -268,6 +278,32 @@ def test_engine_loop_spans_bracket_each_chunk_call():
         assert all(hi <= a or b <= lo for a, b in calls)
     assert all(e["args"]["window"] >= 4 and e["args"]["lanes"] == 2
                for e in evs if e["name"] == "sweep.peek")
+
+
+def test_first_peek_of_each_chunk_call_carries_active():
+    """The active count that picks a chunk call's window rides on the
+    first peek before that call, and fits in the window."""
+    from repro.core import STRATEGIES, Workload
+    from repro.sweep.batch import EngineConfig, build_lanes, simulate_lanes
+
+    rng = np.random.default_rng(1)
+    w = Workload.rigid(submit=np.sort(rng.uniform(0, 150, 30)),
+                       runtime=rng.uniform(20, 120, 30),
+                       nodes_req=rng.choice([1, 2, 4, 8], 30))
+    batch, _ = build_lanes(w, 10, [(STRATEGIES["easy"], 0.0, 0)])
+    obs.configure(enabled=True)
+    simulate_lanes(batch, EngineConfig(window=4, chunk=16))
+    evs = sorted(obs.get_tracer().events(), key=lambda e: e["ts"])
+    pending, paired = None, 0
+    for e in evs:
+        if e["name"] == "sweep.peek" and "active" in e["args"]:
+            assert pending is None  # one per chunk call
+            pending = e["args"]["active"]
+        elif e["name"] in ("sweep.compile", "sweep.execute"):
+            assert pending is not None
+            assert 0 <= pending <= e["args"]["window"]
+            pending, paired = None, paired + 1
+    assert paired >= 2
 
 
 def test_wait_on_a_background_compile_is_compile_time(monkeypatch):

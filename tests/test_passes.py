@@ -153,6 +153,57 @@ def test_backfill_under_shadow_still_happens(engine):
     assert start[1] == pytest.approx(50.0, abs=2 * TINY.tick)
 
 
+@pytest.mark.parametrize("engine", ["des", "sim_jax", "batch"])
+def test_backfill_scans_candidates_in_queue_order(engine):
+    """Backfill is one first-fit scan in queue order: an earlier candidate
+    that runs past the reservation inside the spare-node pool starts
+    before a later one that would finish before it, and the later one,
+    left without free nodes, waits.
+
+    Job 0 (6 nodes) runs until t=100; the 8-node head reserves t=100 with
+    2 spare nodes beyond its need.  Jobs 2 (2 nodes, long) and 3 (4 nodes,
+    short) arrive together: job 2 takes the spare pool, which leaves 2
+    free nodes, too few for job 3."""
+    w = Workload.rigid(
+        submit=np.array([0.0, 1.0, 2.0, 2.0]),
+        runtime=np.array([100.0, 50.0, 1000.0, 10.0]),
+        nodes_req=np.array([6, 8, 2, 4]))
+    start = _starts(engine, w)
+    assert start[2] == pytest.approx(2.0, abs=TINY.tick)
+    assert start[1] == pytest.approx(100.0, abs=2 * TINY.tick)
+    assert start[3] >= start[1] + 1.0
+
+
+@pytest.mark.parametrize("engine", ["des", "batch"])
+def test_long_job_ends_at_the_tick_after_its_end(engine):
+    """A job frees its nodes at the first tick at or after its end, however
+    long it ran: job 0 ends at t=150000.4, so the full-width job 2 starts
+    at t=150001, though job 1's end brings a tick at t=150000 when less
+    than 1e-5 of job 0's work is left."""
+    w = Workload.rigid(submit=np.array([0.0, 0.0, 1.0]),
+                       runtime=np.array([150000.4, 149999.8, 10.0]),
+                       nodes_req=np.array([5, 5, 10]))
+    assert _starts(engine, w)[2] == 150001.0
+
+
+def test_engine_starts_equal_des_on_a_theta_log():
+    """On a seeded Theta log, where capability jobs queue behind wide
+    heads, the batched engine starts every rigid EASY job at the DES's
+    start (within the one tick an arrival may be admitted early)."""
+    from repro.core import CLUSTERS, get_strategy
+    from repro.core.traces import generate
+
+    w, cl = generate("theta", seed=2, scale=0.5), CLUSTERS["theta"]
+    strat = get_strategy("easy")
+    ref = simulate(w, cl, strat)
+    batch, order = build_lanes(w, cl.nodes, [(strat, 0.0, 0)], tick=cl.tick)
+    got = simulate_lanes(batch, EngineConfig())["start_t"][0][
+        np.argsort(order)]
+    np.testing.assert_allclose(got, ref.start, atol=cl.tick)
+    wait_ref = np.mean(ref.start - w.submit)
+    assert np.mean(got - w.submit) == pytest.approx(wait_ref, rel=1e-3)
+
+
 # ----------------------------------------------- three-way engine parity
 @pytest.mark.parametrize("name,prop", [("easy", 0.0), ("min", 0.5),
                                        ("avg", 0.5)])

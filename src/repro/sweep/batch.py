@@ -94,6 +94,7 @@ from repro.core.scenario import DEFAULT_BACKFILL_DEPTH
 from repro.core.speedup import (TransformConfig, amdahl_speedup,
                                 batched_malleable_params)
 from repro.core.strategies import Strategy, effective_queue_order
+from repro.sweep.compact import window_in, window_out
 
 # Bump when engine semantics change: invalidates sweep-cache entries.
 # v2: shadow-time EASY backfill (head reservation) via the shared policy
@@ -730,6 +731,20 @@ def simulate_lanes(batch: BatchedLanes, cfg: EngineConfig,
     return out
 
 
+# The per-job fields a chunk's window carries, and what an empty slot
+# holds: job fields in, job state in and back out.
+_WINDOW_FIELDS = ("submit", "malleable", "min_nodes", "max_nodes", "pfrac",
+                  "inv_ref", "wall_work", "want", "floor", "shrink_floor",
+                  "prio_ref", "on_demand", "pref_nodes", "sort_key")
+_WINDOW_FILLS = (np.float32(np.inf), False, 1, 1, np.float32(0.0),
+                 np.float32(1.0), np.float32(1.0), 1, 1, 1, 0, False, 1,
+                 np.float32(np.inf))  # padding sorts last
+_STATE_FIELDS = ("state", "alloc", "remaining", "start_t", "end_t",
+                 "expand_ops", "shrink_ops")
+_STATE_FILLS = (np.int32(DONE), 0, np.float32(0.0), np.float32(np.nan),
+                np.float32(np.nan), 0, 0)
+
+
 @functools.cache  # unbounded on purpose: see the eviction note in the doc
 def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
               prio_lo: int, prio_hi: int, span_max: int,
@@ -759,7 +774,6 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
     """
     K = cfg.chunk
     E = max(1, int(cfg.events))
-    rows = jnp.arange(B)[:, None]
     INF = jnp.float32(jnp.inf)
 
     def step(bj, capacity, tick, depth, arrival_limit, carry, _):
@@ -954,74 +968,30 @@ def _chunk_fn(cfg: EngineConfig, n: int, B: int, W: int,
             reserve = jnp.maximum(W - n_active, 0)
             sel = active | (pending & (ar < (aptr + reserve)[:, None]))
             pos = prefix_sum(sel) - 1
-            pos = jnp.where(sel & (pos < W), pos, W)  # W: dropped by scatter
-            idx = jnp.full((B, W), n, jnp.int32).at[rows, pos].set(
-                jnp.broadcast_to(ar, (B, n)))
-            slot_ok = idx < n
-            gidx = jnp.minimum(idx, n - 1)
-
-            def g2(a, fill):
-                return jnp.where(slot_ok,
-                                 jnp.take_along_axis(a, gidx, -1), fill)
-
-            bj = BatchedLanes(
-                submit=g2(batch.submit, INF),
-                malleable=g2(batch.malleable, False),
-                min_nodes=g2(batch.min_nodes, 1),
-                max_nodes=g2(batch.max_nodes, 1),
-                pfrac=g2(batch.pfrac, jnp.float32(0.0)),
-                inv_ref=g2(batch.inv_ref, jnp.float32(1.0)),
-                wall_work=g2(batch.wall_work, jnp.float32(1.0)),
-                want=g2(batch.want, 1),
-                floor=g2(batch.floor, 1),
-                shrink_floor=g2(batch.shrink_floor, 1),
-                prio_ref=g2(batch.prio_ref, 0),
-                on_demand=g2(batch.on_demand, False),
-                pref_nodes=g2(batch.pref_nodes, 1),
-                sort_key=g2(batch.sort_key, INF),  # padding sorts last
-                capacity=batch.capacity,
-                tick=batch.tick,
-                backfill_depth=batch.backfill_depth,
-                pool_share=batch.pool_share,
-                steal_margin=batch.steal_margin,
-            )
+            live = sel & (pos < W)
+            per_job = [getattr(batch, f) for f in _WINDOW_FIELDS] + \
+                [full[f] for f in _STATE_FIELDS]
+            win, back = window_in(per_job, live, pos,
+                                  _WINDOW_FILLS + _STATE_FILLS, W)
+            bj = batch._replace(**dict(zip(_WINDOW_FIELDS, win)))
             n_prefetch = jnp.sum(sel & pending, axis=-1)
             lim_idx = aptr + n_prefetch
-            arrival_limit = jnp.where(
-                lim_idx < n,
-                jnp.take_along_axis(
-                    batch.submit, jnp.minimum(lim_idx, n - 1)[:, None],
-                    axis=-1)[:, 0],
-                INF)
+            # the submit time of job lim_idx (INF past the last job)
+            arrival_limit = jnp.min(
+                jnp.where(ar == lim_idx[:, None], batch.submit, INF), axis=-1)
 
-            carry = (
-                g2(state, jnp.int32(DONE)), g2(full["alloc"], 0),
-                g2(full["remaining"], jnp.float32(0.0)),
-                g2(full["start_t"], jnp.float32(jnp.nan)),
-                g2(full["end_t"], jnp.float32(jnp.nan)),
-                g2(full["expand_ops"], 0), g2(full["shrink_ops"], 0),
-                k, retrig, jnp.zeros((B,), bool), bf, nact, ncomp,
-            )
+            carry = (*win[len(_WINDOW_FIELDS):],
+                     k, retrig, jnp.zeros((B,), bool), bf, nact, ncomp)
         carry, ys = jax.lax.scan(
             lambda c, x: step(bj, batch.capacity, batch.tick,
                               batch.backfill_depth, arrival_limit, c, x),
             carry, None, length=K)
-        (bstate, balloc, brem, bstart, bend, beops, bsops,
-         k, retrig, _frozen, bf, nact, ncomp) = carry
-
-        def sc(a, buf):  # idx == n rows are dropped (out of bounds)
-            return a.at[rows, idx].set(buf)
+        k, retrig, _frozen, bf, nact, ncomp = carry[len(_STATE_FIELDS):]
 
         with jax.named_scope("chunk.scatter"):
-            full = dict(
-                state=sc(full["state"], bstate),
-                alloc=sc(full["alloc"], balloc),
-                remaining=sc(full["remaining"], brem),
-                start_t=sc(full["start_t"], bstart),
-                end_t=sc(full["end_t"], bend),
-                expand_ops=sc(full["expand_ops"], beops),
-                shrink_ops=sc(full["shrink_ops"], bsops),
-            )
+            full = dict(zip(_STATE_FIELDS, window_out(
+                [full[f] for f in _STATE_FIELDS],
+                carry[:len(_STATE_FIELDS)], live, back)))
             all_done = jnp.all(full["state"] == DONE)
         ts, busy, qlen = ys  # (K, E, B): E compressed entries per step
         KE = K * E

@@ -116,3 +116,36 @@ def test_engine_chunk_prefix_sums_run_on_the_mxu(one_chip):
     # every matmul is the helper's, named by its scope
     for ln in convs:
         assert "/pass.prefix/" in re.search(r'op_name="([^"]*)"', ln)[1]
+
+
+def _window_moves(sharding, W):
+    """Lines of the haswell-size chunk program, compiled for the device of
+    ``sharding``, that gather or scatter under ``chunk.compact`` or
+    ``chunk.scatter``."""
+    from repro.sweep.batch import EngineConfig, _chunk_fn, chunk_arg_shapes
+
+    n, B = 28259, 16
+    fn = _chunk_fn(EngineConfig(), n, B, W, -2388, 2388, 2388, False,
+                   with_sjf=False, depth_bounded=False)
+    text = fn.lower(*chunk_arg_shapes(n, B, sharding)).compile().as_text()
+    return [ln for ln in text.splitlines()
+            if re.search(r"= \S+ (gather|scatter)\(", ln)
+            and re.search(r'op_name="[^"]*chunk\.(compact|scatter)', ln)]
+
+
+@pytest.mark.parametrize("W", [128, 16384])
+def test_engine_chunk_moves_its_window_without_gathers(one_chip, W):
+    """At the bottom and the top window rung of the haswell deployment,
+    the chunk program lowered for TPU moves its window by shifts and
+    selects: no gather or scatter op carries ``chunk.compact`` or
+    ``chunk.scatter``."""
+    assert not _window_moves(one_chip, W)
+
+
+def test_the_gather_form_shows_its_gathers():
+    """The check above sees the gathers where the program has them: the
+    same program compiled for the CPU, which keeps the gather form."""
+    import jax
+    from jax.sharding import SingleDeviceSharding
+
+    assert _window_moves(SingleDeviceSharding(jax.devices("cpu")[0]), 16384)

@@ -20,8 +20,8 @@ from .speedup import (TabulatedSpeedup, TransformConfig, amdahl_efficiency,
                       amdahl_speedup, nodes_at_efficiency,
                       pfrac_for_reference_efficiency, progress_rate,
                       transform_rigid_to_malleable)
-from .strategies import (AVG, EASY, KEEPPREF, MIN, PREF, STRATEGIES, Strategy,
-                         get_strategy)
+from .strategies import (AVG, EASY, KEEPPREF, MIN, PREF, STRATEGIES,
+                         StrategySpec, get_strategy)
 from . import traces
 
 __all__ = [
@@ -37,6 +37,6 @@ __all__ = [
     "amdahl_speedup", "nodes_at_efficiency",
     "pfrac_for_reference_efficiency", "progress_rate",
     "transform_rigid_to_malleable",
-    "AVG", "EASY", "KEEPPREF", "MIN", "PREF", "STRATEGIES", "Strategy",
+    "AVG", "EASY", "KEEPPREF", "MIN", "PREF", "STRATEGIES", "StrategySpec",
     "get_strategy", "traces",
 ]

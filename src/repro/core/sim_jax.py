@@ -37,7 +37,7 @@ from .jobs import DONE, PENDING, QUEUED, RUNNING, Workload
 from .passes import (PassParams, _speedup_f32 as _speedup, schedule_tick,
                      start_policies)
 from .scenario import DEFAULT_BACKFILL_DEPTH
-from .strategies import Strategy, effective_queue_order
+from .strategies import StrategySpec, effective_queue_order
 
 
 class JobArrays(NamedTuple):
@@ -102,7 +102,7 @@ class SimTrace(NamedTuple):
 )
 def simulate_scan(
     jobs: JobArrays,
-    strategy: Strategy,
+    strategy: StrategySpec,
     capacity: int,
     tick: float,
     n_ticks: int,
@@ -195,7 +195,7 @@ def simulate_scan(
 
 
 def simulate_jax(workload: Workload, capacity: int, tick: float,
-                 n_ticks: int, strategy: Strategy,
+                 n_ticks: int, strategy: StrategySpec,
                  backfill_depth: int = DEFAULT_BACKFILL_DEPTH,
                  queue_order: str = "fcfs",
                  ) -> Tuple[SimState, SimTrace]:
@@ -208,7 +208,7 @@ def simulate_jax(workload: Workload, capacity: int, tick: float,
 
 
 @functools.lru_cache(maxsize=None)
-def _batched_sim(strategy: Strategy, capacity: int, tick: float,
+def _batched_sim(strategy: StrategySpec, capacity: int, tick: float,
                  n_ticks: int, with_classes: bool, queue_order: str):
     """One jitted vmap of :func:`simulate_scan` per static configuration."""
     return jax.jit(jax.vmap(
@@ -218,8 +218,8 @@ def _batched_sim(strategy: Strategy, capacity: int, tick: float,
                                           queue_order=queue_order)))
 
 
-def simulate_scan_batch(jobs: JobArrays, strategy: Strategy, capacity: int,
-                        tick: float, n_ticks: int,
+def simulate_scan_batch(jobs: JobArrays, strategy: StrategySpec,
+                        capacity: int, tick: float, n_ticks: int,
                         backfill_depth=None,
                         queue_order: str = "fcfs") -> Tuple[SimState, SimTrace]:
     """Batched entry point: ``jobs`` fields are (B, n); one lane per variant.

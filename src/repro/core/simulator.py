@@ -45,7 +45,7 @@ from .passes import (balanced_expand, balanced_shrink,
                      start_policies)
 from .scenario import DEFAULT_BACKFILL_DEPTH
 from .speedup import amdahl_speedup
-from .strategies import Strategy, effective_queue_order
+from .strategies import StrategySpec, effective_queue_order
 
 _EPS = 1e-9
 
@@ -108,7 +108,7 @@ class Simulator:
         self,
         workload: Workload,
         cluster: Cluster,
-        strategy: Strategy,
+        strategy: StrategySpec,
         backfill_depth: int = DEFAULT_BACKFILL_DEPTH,
         dense_ticks: bool = False,
         queue_order: str = "fcfs",
@@ -491,6 +491,6 @@ class Simulator:
         )
 
 
-def simulate(workload: Workload, cluster: Cluster, strategy: Strategy,
+def simulate(workload: Workload, cluster: Cluster, strategy: StrategySpec,
              **kw) -> SimResult:
     return Simulator(workload, cluster, strategy, **kw).run()

@@ -109,10 +109,6 @@ class StrategySpec:
         return {"min": mn, "pref": pref, "req": req}[which]
 
 
-# Back-compat alias: pre-registry code constructed/annotated `Strategy`.
-Strategy = StrategySpec
-
-
 # Rigid baseline: malleable metadata ignored; every job starts at its rigid
 # request and is never resized.
 EASY = StrategySpec(name="easy", malleable=False)

@@ -36,14 +36,11 @@ from .report import (SCENARIO_AXES, axis_key, best_improvements,
 from .run import run_experiment, sweep_scenario_axis, write_artifact
 
 
-def main(argv=None, prog=None, epilog=None) -> int:
-    """Run the experiment CLI.  ``prog``/``epilog`` let delegating entry
-    points (``python -m repro.sweep``) keep their own ``--help`` identity
-    and document engine-specific flags."""
+def main(argv=None) -> int:
+    """Run the experiment CLI."""
     ap = argparse.ArgumentParser(
-        prog=prog or "python -m repro.experiments",
+        prog="python -m repro.experiments",
         description=__doc__.splitlines()[0],
-        epilog=epilog,
         formatter_class=argparse.RawDescriptionHelpFormatter)
     add_spec_arguments(ap)
     add_backend_arguments(ap)
@@ -67,8 +64,7 @@ def main(argv=None, prog=None, epilog=None) -> int:
                          "(numbers, or fcfs/sjf for queue_order)")
     ap.add_argument("--out", default="",
                     help="artifact path; with several workloads one file "
-                         "holding {results: {workload: ...}} is written "
-                         "(the historical python -m repro.sweep layout)")
+                         "holding {results: {workload: ...}} is written")
     args = ap.parse_args(argv)
     if args.require_crosscheck and not args.crosscheck:
         ap.error("--require-crosscheck needs --crosscheck N")

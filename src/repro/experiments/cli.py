@@ -1,9 +1,10 @@
 """Shared argparse wiring: CLI flags <-> :class:`ExperimentSpec`.
 
-Every grid CLI (``python -m repro.experiments``, ``python -m repro.sweep``,
-``python -m benchmarks.sweep``, ``examples/paper_repro.py``) builds its
-spec through these helpers, so the scenario axes and engine choice are
-uniformly sweepable and no entry point grows a private grid dialect.
+Every grid CLI (``python -m repro.experiments``, ``python -m
+benchmarks.sweep``, ``python -m benchmarks.run``, ``python -m repro.serve``)
+builds its spec through these helpers, so the scenario axes and engine
+choice are uniformly sweepable and no entry point grows a private grid
+dialect.
 """
 from __future__ import annotations
 
@@ -140,32 +141,26 @@ def add_execution_arguments(ap: argparse.ArgumentParser) -> None:
     (chunked/sharded cells are bit-identical to the monolithic batch;
     ``tests/test_shard.py``), so none of them ever enters a spec or cell
     fingerprint.  Shared by every jax-capable grid CLI, including
-    ``benchmarks/run.py`` which manages its own cache/worker flags."""
-    ap.add_argument("--window", type=int, default=0,
-                    help="[jax] active-set window ladder floor (0 = start "
-                         "at the statics-predicted bucket)")
-    ap.add_argument("--events", type=int, default=4,
-                    help="[jax] per-lane events retired per scan step "
-                         "(event compression; results-invariant, 1 "
-                         "disables)")
-    ap.add_argument("--no-aot-warmup", dest="aot_warmup",
-                    action="store_false", default=True,
-                    help="[jax] disable background pre-compilation of the "
-                         "window ladder's upper buckets")
-    ap.add_argument("--chunk", type=int, default=160,
+    ``benchmarks/run.py`` which manages its own cache/worker flags.  A flag
+    left out is absent from the parsed namespace, so the engine's own
+    default holds (:func:`repro.experiments.backend_jax.engine_configs`)."""
+    ap.add_argument("--window", type=int, default=argparse.SUPPRESS,
+                    help="[jax] active-set window ladder floor (0, the "
+                         "default: start at the statics-predicted bucket)")
+    ap.add_argument("--chunk", type=int, default=argparse.SUPPRESS,
                     help="[jax] scan steps between window compactions")
-    ap.add_argument("--chunk-lanes", "--max-lane-width", dest="chunk_lanes",
-                    type=int, default=0, metavar="N",
+    ap.add_argument("--chunk-lanes", type=int, default=argparse.SUPPRESS,
+                    metavar="N",
                     help="[jax] max device-resident lanes per chunk; the "
                          "batch streams as sequential chunks, each flushed "
                          "to the cell store on completion so interrupted "
-                         "runs resume chunk-by-chunk (0 = whole batch at "
-                         "once; see docs/paper-scale.md)")
-    ap.add_argument("--devices", type=int, default=0,
+                         "runs resume chunk-by-chunk (0, the default: whole "
+                         "batch at once; see docs/paper-scale.md)")
+    ap.add_argument("--devices", type=int, default=argparse.SUPPRESS,
                     help="[jax] lane-shard each chunk across N local "
                          "devices over a 1-D mesh (0 = all local devices, "
                          "1 = no sharding)")
-    ap.add_argument("--expand-backend", default="bisect",
+    ap.add_argument("--expand-backend", default=argparse.SUPPRESS,
                     choices=["bisect", "pallas", "pallas-interpret",
                              "fused", "fused-interpret"],
                     help="[jax] Step-3 greedy expand backend: sort-free "
@@ -228,11 +223,17 @@ def flush_observability(args: argparse.Namespace,
                 print(f"[obs] wrote {p}")
 
 
+EXECUTION_OPTIONS = ("window", "chunk", "chunk_lanes", "devices",
+                     "expand_backend")
+
+
+def execution_options_from_args(args: argparse.Namespace) -> dict:
+    """The execution flags the user gave, and no others."""
+    return {k: getattr(args, k) for k in EXECUTION_OPTIONS
+            if hasattr(args, k)}
+
+
 def backend_options_from_args(args: argparse.Namespace) -> dict:
-    return {"workers": getattr(args, "workers", 0), "window": args.window,
-            "chunk": args.chunk, "chunk_lanes": args.chunk_lanes,
-            "devices": args.devices,
-            "expand_backend": args.expand_backend,
-            "events": getattr(args, "events", 4),
-            "aot_warmup": bool(getattr(args, "aot_warmup", True)),
-            "progress": bool(getattr(args, "progress", False))}
+    return {"workers": getattr(args, "workers", 0),
+            "progress": bool(getattr(args, "progress", False)),
+            **execution_options_from_args(args)}

@@ -1,7 +1,7 @@
 """Execute an :class:`ExperimentSpec`: store -> backend -> artifact.
 
 One entry point, :func:`run_experiment`, for every grid consumer
-(``benchmarks/sweep.py``, ``benchmarks/run.py``, ``python -m repro.sweep``,
+(``benchmarks/sweep.py``, ``benchmarks/run.py``,
 ``python -m repro.experiments``, ``examples/paper_repro.py``):
 
 1. fingerprint every (workload, cell) of the spec and read the shared

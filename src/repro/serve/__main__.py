@@ -33,6 +33,7 @@ from repro.experiments.cli import (add_execution_arguments,
                                    add_observability_arguments,
                                    add_scenario_arguments,
                                    configure_observability,
+                                   execution_options_from_args,
                                    flush_observability, scenario_from_args)
 from repro.experiments.spec import ENGINES, ExperimentSpec
 
@@ -106,18 +107,13 @@ def base_spec_from_args(args: argparse.Namespace) -> ExperimentSpec:
 
 
 def engine_from_args(args: argparse.Namespace) -> WhatIfEngine:
-    backend_options = {
-        "window": args.window, "chunk": args.chunk,
-        "chunk_lanes": args.chunk_lanes, "devices": args.devices or 1,
-        "expand_backend": args.expand_backend, "events": args.events,
-        "aot_warmup": args.aot_warmup}
     return WhatIfEngine(
         base_spec_from_args(args),
         cache_dir=args.cache_dir or None,
         max_batch=args.max_batch,
         max_wait_s=args.max_wait_ms / 1000.0,
         max_queue=args.max_queue,
-        backend_options=backend_options,
+        backend_options=execution_options_from_args(args),
         start=False)
 
 

@@ -301,6 +301,9 @@ class WhatIfEngine:
         self.max_queue = max_queue
         self.memo_limit = memo_limit
         self.backend_options = dict(backend_options or {})
+        # a query batch runs on one device unless the caller asks for more
+        self.backend_options["devices"] = (
+            self.backend_options.get("devices") or 1)
         self.store = SweepCache(cache_dir) if cache_dir else None
         self._executor = executor or self._default_executor()
         self._clock = clock or MonotonicClock()
@@ -565,98 +568,41 @@ class WhatIfEngine:
         return self._wl_memo[key]
 
     def _jax_executor(self, batch: List[_Pending]) -> None:
-        """Coalesced path: every query is one padded lane of one device
-        batch per pass structure; results stream back per chunk.
+        """Coalesced path: every query is one lane group of the sweep
+        backend's lane executor (:func:`backend_jax.lane_batches`), so the
+        whole batch runs as one padded device batch per pass structure and
+        results stream back per chunk.
 
         Heterogeneity rides as lane data — workload, backfill depth and
         queue order are per-lane fields of :class:`BatchedLanes` — so the
-        whole batch shares one compilation per structure bucket, exactly
-        like the sweep backend (:mod:`repro.experiments.backend_jax`).
+        whole batch shares one compilation per structure bucket, and each
+        served cell is bit-identical to the sweep's.
         """
-        import numpy as np
+        from repro.experiments.backend_jax import LaneGroup, lane_batches
 
-        from repro.core import DONE, get_strategy
-        from repro.sweep.batch import (EngineConfig, build_lanes,
-                                       concat_lanes)
-        from repro.sweep.shard import ShardConfig, simulate_lanes_chunked
-
-        opts = self.backend_options
-        groups: Dict[str, List[_Pending]] = {}
+        groups = []
         for p in batch:
-            groups.setdefault(get_strategy(p.cell[0]).structure,
-                              []).append(p)
-        for structure, group in groups.items():
             try:
-                batches, t0s, t1s, caps = [], [], [], []
-                for p in group:
-                    cl, w_rigid, window = self._realized(p.spec, p.workload)
-                    lanes = [(get_strategy(p.cell[0]), p.cell[1], p.cell[2])]
-                    b, _order = build_lanes(
-                        w_rigid, cl.nodes, lanes, config=p.spec.transform,
-                        tick=cl.tick,
-                        backfill_depth=p.spec.scenario.backfill_depth,
-                        queue_order=p.spec.scenario.queue_order)
-                    batches.append(b)
-                    t0s.append(window.t0)
-                    t1s.append(window.t1)
-                    caps.append(cl.nodes)
-                big = concat_lanes(batches) if len(batches) > 1 else batches[0]
-                cfg = EngineConfig(
-                    structure=structure,
-                    window=int(opts.get("window", 0)),
-                    chunk=int(opts.get("chunk", 160)),
-                    max_steps_factor=int(opts.get("max_steps_factor", 16)),
-                    expand_backend=opts.get("expand_backend", "bisect"),
-                    events=int(opts.get("events", 4)),
-                    aot_warmup=bool(opts.get("aot_warmup", True)))
-                shard = ShardConfig(
-                    chunk_lanes=int(opts.get("chunk_lanes", 0)),
-                    devices=int(opts.get("devices", 1) or 1))
-                win0, win1 = np.asarray(t0s), np.asarray(t1s)
-                caps_arr = np.asarray(caps)
-                stream = simulate_lanes_chunked(big, cfg, shard,
-                                                verbose=False)
-                for ch in self._metered_chunks(stream, structure):
-                    res = ch.results
-                    per_lane = self._chunk_metrics(
-                        res, big, ch, win0, win1, caps_arr)
-                    lane_done = np.all(res["state"] == DONE, axis=1)
-                    for p, m, ok in zip(group[ch.lo:ch.hi], per_lane,
-                                        lane_done):
+                cl, w_rigid, window = self._realized(p.spec, p.workload)
+            except Exception as exc:  # noqa: BLE001 — poison one query
+                p.reject(exc)
+                continue
+            groups.append(LaneGroup(
+                cl, w_rigid, window, p.spec.transform,
+                p.spec.scenario.backfill_depth, p.spec.scenario.queue_order,
+                [(p, p.cell)]))
+        for lanes in lane_batches(groups, self.backend_options):
+            try:
+                for out in lanes.chunks():
+                    obs.counter("serve.chunks")
+                    for p, m, ok in zip(out.keys, out.metrics, out.done):
                         if bool(ok):
                             p.resolve(m)
                         else:
                             p.reject(QueryFailedError(
                                 f"lane for {p.query.to_dict()} hit the "
                                 "engine step budget before completing"))
-            except Exception as exc:  # noqa: BLE001 — poison this group
-                for p in group:
+            except Exception as exc:  # noqa: BLE001 — poison this structure
+                for p in lanes.keys:
                     if not p.done:
                         p.reject(exc)
-
-    @staticmethod
-    def _metered_chunks(stream, structure: str):
-        for ch in stream:
-            obs.counter("serve.chunks")
-            yield ch
-
-    @staticmethod
-    def _chunk_metrics(res, big, ch, win0, win1, caps_arr):
-        """Per-lane metric dicts for one chunk, sched counters attached —
-        the exact recipe of :func:`backend_jax.run_cells`, so serve-path
-        cells are bit-identical to sweep-path cells."""
-        import numpy as np
-
-        from repro.sweep.metrics_jax import batched_metrics
-
-        per_lane = batched_metrics(
-            res, big.submit[ch.lo:ch.hi], big.malleable[ch.lo:ch.hi],
-            (win0[ch.lo:ch.hi], win1[ch.lo:ch.hi]), caps_arr[ch.lo:ch.hi])
-        shrink_ev = np.sum(res["shrink_ops"], axis=1)
-        expand_ev = np.sum(res["expand_ops"], axis=1)
-        for i, m in enumerate(per_lane):
-            m["sched_backfill_starts"] = float(res["bf_starts"][i])
-            m["sched_shrink_events"] = float(shrink_ev[i])
-            m["sched_expand_events"] = float(expand_ev[i])
-            m["sched_invocations"] = float(res["sched_steps"][i])
-        return per_lane

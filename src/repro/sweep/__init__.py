@@ -7,8 +7,6 @@
 # - metrics_jax: on-device port of repro.core.metrics.run_metrics
 # - cache:       engine-agnostic content-hash cell store (shared with the
 #                DES backend of repro.experiments)
-# - runner:      jax-engine CLI + back-compat wrappers over the declarative
-#                experiment layer (repro.experiments)
 #
 # Exports resolve lazily (PEP 562) so jax-free consumers — the cell store,
 # the DES experiment backend — can import from this package without paying
@@ -26,7 +24,6 @@ _EXPORTS = {
     "SweepCache": "cache", "cell_fingerprint": "cache",
     "engine_version": "cache",
     "batched_metrics": "metrics_jax",
-    "sweep_workload_jax": "runner", "sweep_workloads_jax": "runner",
 }
 
 __all__ = sorted(_EXPORTS)
@@ -37,7 +34,6 @@ if TYPE_CHECKING:  # pragma: no cover
                         simulate_lanes, take_lanes)
     from .cache import SweepCache, cell_fingerprint, engine_version
     from .metrics_jax import batched_metrics
-    from .runner import sweep_workload_jax, sweep_workloads_jax
     from .shard import (ChunkResult, ShardConfig, chunk_plan, describe_plan,
                         simulate_lanes_chunked)
 

@@ -71,9 +71,9 @@ shadow_reservation`) and scans candidates in queue order as the DES does,
 but a pass stops after ``fill_rounds`` skipped candidates and the scan goes
 on at the next tick; shrink/expand tie-break in FCFS order
 rather than the DES running-set insertion order; scheduling converges over
-subsequent ticks instead of an in-tick fixpoint.  ``runner.py
---crosscheck`` quantifies the resulting metric deltas against the DES per
-cell.
+subsequent ticks instead of an in-tick fixpoint.  ``python -m
+repro.experiments --engine jax --crosscheck`` quantifies the resulting
+metric deltas against the DES per cell.
 """
 from __future__ import annotations
 
@@ -93,7 +93,7 @@ from repro.core.passes import (PassParams, prefix_sum, schedule_tick,
 from repro.core.scenario import DEFAULT_BACKFILL_DEPTH
 from repro.core.speedup import (TransformConfig, amdahl_speedup,
                                 batched_malleable_params)
-from repro.core.strategies import Strategy, effective_queue_order
+from repro.core.strategies import StrategySpec, effective_queue_order
 from repro.sweep.compact import window_in, window_out
 
 # Bump when engine semantics change: invalidates sweep-cache entries.
@@ -183,7 +183,7 @@ class EngineConfig:
 def build_lanes(
     workload: Workload,
     cluster_nodes: int,
-    lanes: Sequence[Tuple[Strategy, float, int]],
+    lanes: Sequence[Tuple[StrategySpec, float, int]],
     config: TransformConfig = TransformConfig(),
     tick: float = 1.0,
     backfill_depth: int = DEFAULT_BACKFILL_DEPTH,

@@ -6,10 +6,10 @@ fixed path ``<repo>/artifacts/xla_cache`` — fixed because a later run
 only finds its compilations again under the same path, so it is never
 derived from a temp dir, a pid or the time.
 
-Entry points (``python -m repro.sweep`` / ``repro.experiments`` /
-``repro.serve``, ``benchmarks/run.py``, ``benchmarks/serve_load.py``,
-``chip_smoke.py``) call :func:`enable_compilation_cache` once, before
-their first compilation; library code never does.
+Entry points (``python -m repro.experiments`` / ``repro.serve``,
+``benchmarks/run.py``, ``benchmarks/serve_load.py``, ``chip_smoke.py``)
+call :func:`enable_compilation_cache` once, before their first
+compilation; library code never does.
 """
 from __future__ import annotations
 

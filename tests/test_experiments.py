@@ -446,6 +446,9 @@ def test_cli_roundtrip_scenario_axes(engine):
                                            backfill_depth=64)
     opts = backend_options_from_args(args)
     assert opts["workers"] == 2 and opts["window"] == 32
+    # execution flags left out stay out: the engine's defaults hold
+    assert not ({"chunk", "chunk_lanes", "devices", "expand_backend"}
+                & set(opts))
 
 
 def test_cli_default_backfill_depth_matches_des_default():

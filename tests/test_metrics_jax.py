@@ -8,7 +8,7 @@ Two layers:
 2. *Cross-engine parity*: batched-engine metrics vs. ``run_metrics`` on the
    numpy DES outputs for >= 2 traces x 2 strategies, within the documented
    tick-quantization / backfill-lite tolerances
-   (``repro.sweep.runner.CROSSCHECK_TOLERANCES``).
+   (``repro.experiments.crosscheck.CROSSCHECK_TOLERANCES``).
 """
 import numpy as np
 import pytest
@@ -16,9 +16,9 @@ import pytest
 from repro.core import (CLUSTERS, Window, get_strategy, run_metrics,
                         simulate, traces, transform_rigid_to_malleable)
 from repro.core.simulator import SimResult
+from repro.experiments.crosscheck import CROSSCHECK_TOLERANCES
 from repro.sweep.batch import EngineConfig, build_lanes, simulate_lanes
 from repro.sweep.metrics_jax import batched_metrics
-from repro.sweep.runner import CROSSCHECK_TOLERANCES
 
 CASES = [("haswell", "easy", 0.0), ("haswell", "min", 1.0),
          ("knl", "easy", 0.0), ("knl", "keeppref", 1.0)]

@@ -101,6 +101,14 @@ def make_engine(executor, *, clock=None, start=False, **over):
 
 # ----------------------------------------------------------------------
 # coalescer mechanics (deterministic, no real sleeps)
+@pytest.mark.parametrize("given,devices", [
+    (None, 1), ({"devices": 0}, 1), ({"devices": 2}, 2)])
+def test_query_batches_run_on_one_device_unless_asked(given, devices):
+    eng = make_engine(RecordingExecutor(), backend_options=given)
+    assert eng.backend_options["devices"] == devices
+    eng.close()
+
+
 def test_full_batch_dispatches_without_waiting():
     """N=max_batch queries land in ONE batch, no clock advance needed."""
     ex = RecordingExecutor()
@@ -381,14 +389,18 @@ def test_hit_paths_and_single_miss(tmp_path):
     assert computed == from_store == from_memo
 
 
-def test_jax_coalesced_parity_with_run_experiment(tmp_path):
-    """The padded-device-batch miss path (greedy + balanced structures in
-    one storm) is bit-identical to the jax run_experiment backend."""
+@pytest.mark.parametrize("strategies", [
+    ("min", "avg"),                              # greedy + balanced
+    ("pref_common_pool", "steal_agreement"),     # pooled + stealing
+])
+def test_jax_coalesced_parity_with_run_experiment(tmp_path, strategies):
+    """The padded-device-batch miss path (two pass structures in one
+    storm) is bit-identical to the jax run_experiment backend."""
     from repro.experiments.run import run_experiment
     from repro.sweep.cache import SweepCache
 
     spec = base_spec(engine="jax", proportions=(0.0, 0.5),
-                     strategies=("min", "avg"), seeds=1)
+                     strategies=strategies, seeds=1)
     run_experiment(spec, cache_dir=str(tmp_path / "direct"), verbose=False)
 
     eng = WhatIfEngine(spec, cache_dir=str(tmp_path / "served"),
@@ -407,6 +419,28 @@ def test_jax_coalesced_parity_with_run_experiment(tmp_path):
         a, b = direct.get(fp), served.get(fp)
         assert a is not None and b is not None, q
         assert a == b, f"jax serve path diverged for {q}"
+
+
+def test_jax_realization_failure_poisons_only_its_query():
+    """A query whose workload fails to realise is rejected alone; the
+    rest of its batch still runs through the lane executor."""
+    eng = WhatIfEngine(base_spec(engine="jax"), cache_dir=None, max_batch=2,
+                       max_wait_s=10.0, start=False)
+    realized, calls = eng._realized, []
+
+    def first_call_fails(spec, name):
+        calls.append(name)
+        if len(calls) == 1:
+            raise RuntimeError("trace unreadable")
+        return realized(spec, name)
+
+    eng._realized = first_call_fails
+    fa, fc = eng.submit(QA), eng.submit(QC)
+    eng.start()
+    with pytest.raises(QueryFailedError, match="trace unreadable"):
+        fa.result(timeout=600)
+    assert "sched_invocations" in fc.result(timeout=600)
+    eng.close()
 
 
 def test_seeded_interleaving_order_independence(tmp_path):
